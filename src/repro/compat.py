@@ -1,64 +1,27 @@
-"""Version-compat shims over the jax API surface this codebase targets.
+"""The mesh and ``shard_map`` spellings this codebase uses, in one place.
 
-The code is written against the current jax API (``jax.shard_map``,
-``jax.sharding.AxisType``, ``pltpu.CompilerParams``); older releases (the
-0.4.x line this container ships) spell those differently or not at all.
-Everything version-sensitive goes through this module so call sites stay on
-the forward-looking spelling.
+``make_mesh`` builds meshes whose axes are all Auto (the sharding rules in
+:mod:`repro.parallel.sharding` rely on GSPMD propagation), and
+``shard_map`` turns a set of manual axes into ``jax.shard_map``'s
+``axis_names`` with value-variance checking off by default.
 """
 
 from __future__ import annotations
 
-from typing import Any, Sequence
+from typing import Sequence
 
 import jax
 
 
-def tpu_compiler_params(**kwargs) -> Any:
-    """``pltpu.CompilerParams(...)`` (new) / ``pltpu.TPUCompilerParams`` (0.4.x)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with all axes Auto; drops ``axis_types`` on old jax."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def get_abstract_mesh():
-    """``jax.sharding.get_abstract_mesh`` or None when the concept is absent."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    return fn() if fn is not None else None
+    """``jax.make_mesh`` with every axis Auto."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check_vma=False):
-    """``jax.shard_map`` with partial-manual axes.
-
-    On 0.4.x this maps to ``jax.experimental.shard_map.shard_map`` where the
-    manual/auto split is expressed inversely (``auto`` = mesh axes *not* in
-    ``axis_names``) and ``check_vma`` is called ``check_rep`` (which must be
-    off for partial-auto regions).
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # an empty axis_names means "all axes manual" (the new-jax default), so
-    # only a non-empty set carves out auto axes here
-    auto: frozenset = frozenset()
-    if axis_names:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=bool(check_vma) and not auto, auto=auto,
-    )
+    """``jax.shard_map``; ``axis_names`` (default: all axes) are manual."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma)
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)

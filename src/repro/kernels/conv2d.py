@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 
 def _conv_kernel(x_ref, w_ref, o_ref, acc_ref, *, kh, kw, stride, th, ow, slab_h):
@@ -82,7 +81,7 @@ def conv2d_ntx(
         out_specs=pl.BlockSpec((1, th, ow, cout), lambda b, t: (b, t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, n_tiles * th, ow, cout), x.dtype),
         scratch_shapes=[pltpu.VMEM((th, ow, cout), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

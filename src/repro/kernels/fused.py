@@ -7,12 +7,11 @@ region into ONE ``pallas_call`` — the software analogue of the NTX datapath
 streaming a whole loop nest through the FMAC pipeline instead of taking a
 per-op offload round trip.
 
-The kernel shape follows :mod:`repro.kernels.streaming`'s hand-rolled DMA
-idiom, lifted from the k-loop to the batch-tile grid:
+The kernel walks a batch-tile grid:
 
-  * the grid walks batch tiles; every *streamed* (batched) region input
-    owns two VMEM tile buffers and a ``make_async_copy`` prefetches tile
-    k+1 out of ANY/HBM while tile k computes;
+  * every *streamed* (batched) region input and output moves in batch
+    tiles, which the Pallas pipeline double-buffers — tile k+1 streams in
+    from HBM while tile k computes;
   * params and momentum state ride in as resident VMEM blocks;
   * every intermediate edge of the region lives in kernel scratch values —
     conv pre-activations, relu masks, im2col columns never touch HBM;
@@ -21,7 +20,8 @@ idiom, lifted from the k-loop to the batch-tile grid:
     a fwd or bwd chain plus its update is one dispatch.
 
 Convolutions are expressed per tile as statically-unrolled im2col plus an
-MXU ``jnp.dot`` with an fp32 accumulator (the NTX wide-accumulation story);
+MXU ``jnp.dot`` with an fp32 accumulator at full fp32 contraction
+precision (the NTX wide-accumulation story);
 the input gradient is the transposed conv — dilate dy by the stride, pad by
 ``k-1-p``, correlate with the rotated kernel — all inside the same tile.
 """
@@ -43,17 +43,33 @@ from repro.lower.rules import (
     SoftmaxXentSpec,
 )
 
-N_BUFFERS = 2  # double buffering, as in kernels.streaming / runtime.dma
+#: Scoped VMEM a region kernel may take. The default scoped limit (16 MiB
+#: on v5e) is less than a tile of a small-channel image needs once its
+#: channels pad out to 128 lanes; the chip has 128 MiB.
+VMEM_LIMIT_BYTES = 100 * 2**20
 
 
-def _batch_block(batch: int) -> int:
+def _dot(a, b):
+    """fp32 MXU contraction; the TPU default would round f32 to bf16."""
+    return jnp.dot(
+        a, b, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
+def _batch_block(batch: int, ranks) -> int:
     """Batch-tile size: two grid steps when the batch splits evenly.
 
     Small tiles keep the per-tile im2col slices cheap (the measured cost
-    center) while two grid steps give the prefetch something to overlap.
+    center) while two grid steps give the pipeline something to overlap.
+    ``ranks`` are those of the batched edges: in a 2-D edge the batch is
+    the sublane axis, whose tile must be a multiple of 8 rows or the whole
+    batch, so such regions only split batches of 16 and up.
     """
-    if batch >= 4 and batch % 2 == 0:
-        return batch // 2
+    align = 8 if min(ranks, default=3) <= 2 else 1
+    half = batch // 2
+    if batch >= 4 and batch % 2 == 0 and half % align == 0:
+        return half
     return batch
 
 
@@ -63,18 +79,46 @@ def _pad_hw(x, ph: int, pw: int):
     return x
 
 
+def _phase_split(x, s: int, h: int, w: int):
+    """(bn, H, W, c) -> (bn, h, s, w, s, c): each spatial axis as (blocks,
+    phase), zero-padded on the high side to ``h*s`` x ``w*s``.
+
+    Mosaic has no strided vector slice, and jnp lowers a slice strided in
+    two axes to a gather, which Mosaic refuses too. Splitting the axes
+    into stride phases turns ``x[:, a::s, b::s]`` into a unit-stride
+    window of one phase.
+    """
+    bn, hx, wx, c = x.shape
+    x = jnp.pad(x, ((0, 0), (0, h * s - hx), (0, w * s - wx), (0, 0)))
+    return x.reshape(bn, h, s, w, s, c)
+
+
+def _windows(xp, kh: int, kw: int, oh: int, ow: int, s: int):
+    """The kh*kw im2col taps ``xp[:, dh::s, dw::s][:, :oh, :ow]``."""
+    if s == 1:
+        return [
+            xp[:, dh : dh + oh, dw : dw + ow, :]
+            for dh in range(kh)
+            for dw in range(kw)
+        ]
+    h = max(-(-xp.shape[1] // s), (kh - 1) // s + oh)
+    w = max(-(-xp.shape[2] // s), (kw - 1) // s + ow)
+    r = _phase_split(xp, s, h, w)
+    taps = []
+    for dh in range(kh):
+        for dw in range(kw):
+            qh, rh = divmod(dh, s)
+            qw, rw = divmod(dw, s)
+            taps.append(r[:, qh : qh + oh, rh, qw : qw + ow, rw, :])
+    return taps
+
+
 def _conv_cols(xp, spec: Conv2dSpec):
     """Unrolled im2col on a padded tile -> (bn*oh*ow, kh*kw*cin)."""
     bn = xp.shape[0]
-    s = spec.stride
     oh, ow = spec.out_h, spec.out_w
     cols = jnp.concatenate(
-        [
-            xp[:, dh : dh + oh * s : s, dw : dw + ow * s : s, :]
-            for dh in range(spec.kh)
-            for dw in range(spec.kw)
-        ],
-        axis=-1,
+        _windows(xp, spec.kh, spec.kw, oh, ow, spec.stride), axis=-1
     )
     return cols.reshape(bn * oh * ow, spec.kh * spec.kw * spec.cin)
 
@@ -83,7 +127,7 @@ def _conv_fwd_tile(x, w, spec: Conv2dSpec):
     p = spec.padding
     cols = _conv_cols(_pad_hw(x, p, p), spec)
     wf = w.reshape(spec.kh * spec.kw * spec.cin, spec.cout)
-    y = jnp.dot(cols, wf, preferred_element_type=jnp.float32)
+    y = _dot(cols, wf)
     return y.reshape(x.shape[0], spec.out_h, spec.out_w, spec.cout)
 
 
@@ -92,7 +136,7 @@ def _conv_dw_tile(x, dy, spec: Conv2dSpec):
     p = spec.padding
     cols = _conv_cols(_pad_hw(x, p, p), spec)
     dyf = dy.reshape(-1, spec.cout)
-    dwf = jnp.dot(cols.T, dyf, preferred_element_type=jnp.float32)
+    dwf = _dot(cols.T, dyf)
     return dwf.reshape(spec.kh, spec.kw, spec.cin, spec.cout)
 
 
@@ -102,28 +146,34 @@ def _conv_dx_tile(dy, w, spec: Conv2dSpec):
     s, p = spec.stride, spec.padding
     oh, ow = spec.out_h, spec.out_w
     if s > 1:
-        z = jnp.zeros(
-            (bn, (oh - 1) * s + 1, (ow - 1) * s + 1, spec.cout), dy.dtype
-        )
-        z = z.at[:, ::s, ::s, :].set(dy)
+        # dilate by the stride: dy lands on phase (0, 0) of each s x s
+        # block (the inverse of _phase_split; a strided scatter would be
+        # refused like the strided slice)
+        z = jnp.pad(
+            dy[:, :, None, :, None, :],
+            ((0, 0), (0, 0), (0, s - 1), (0, 0), (0, s - 1), (0, 0)),
+        ).reshape(bn, oh * s, ow * s, spec.cout)
+        z = z[:, : (oh - 1) * s + 1, : (ow - 1) * s + 1, :]
     else:
         z = dy
     qh, qw = spec.kh - 1 - p, spec.kw - 1 - p
     rh = (spec.in_h + 2 * p - spec.kh) % s
     rw = (spec.in_w + 2 * p - spec.kw) % s
     z = jnp.pad(z, ((0, 0), (qh, qh + rh), (qw, qw + rw), (0, 0)))
-    w_hat = w[::-1, ::-1, :, :].transpose(0, 1, 3, 2)  # (kh, kw, cout, cin)
-    cols = jnp.concatenate(
+    # rot180(w) with cin/cout swapped, tap by tap (Mosaic has no reverse):
+    # (kh*kw*cout, cin) rows in the im2col's (dh, dw, cout) order
+    w_hat = jnp.concatenate(
         [
-            z[:, dh : dh + spec.in_h, dw : dw + spec.in_w, :]
+            w[spec.kh - 1 - dh, spec.kw - 1 - dw].T
             for dh in range(spec.kh)
             for dw in range(spec.kw)
         ],
-        axis=-1,
-    ).reshape(bn * spec.in_h * spec.in_w, spec.kh * spec.kw * spec.cout)
-    dxf = jnp.dot(
-        cols, w_hat.reshape(-1, spec.cin), preferred_element_type=jnp.float32
+        axis=0,
     )
+    cols = jnp.concatenate(
+        _windows(z, spec.kh, spec.kw, spec.in_h, spec.in_w, 1), axis=-1
+    ).reshape(bn * spec.in_h * spec.in_w, spec.kh * spec.kw * spec.cout)
+    dxf = _dot(cols, w_hat)
     return dxf.reshape(bn, spec.in_h, spec.in_w, spec.cin)
 
 
@@ -139,21 +189,54 @@ def _pool_dx_tile(x, g, spec: MaxPool2dSpec):
 
     Ties route the gradient to the first maximal tap in window order —
     the same tie-breaking as XLA's select-and-scatter, so the fused chain
-    matches ``jax.vjp`` of ``reduce_window`` bit for bit.
+    matches ``jax.vjp`` of ``reduce_window`` bit for bit. The taps are
+    walked one by one (Mosaic has no cumsum to find the first match).
     """
     bn, h, w, c = x.shape
     k = spec.window
     oh, ow = h // k, w // k
     xw = x.reshape(bn, oh, k, ow, k, c)
     y = xw.max(axis=(2, 4))
-    eq = xw == y[:, :, None, :, None, :]
-    taps = eq.transpose(0, 1, 3, 5, 2, 4).reshape(bn, oh, ow, c, k * k)
-    first = taps & (jnp.cumsum(taps.astype(jnp.int32), axis=-1) == 1)
-    dx = first.astype(g.dtype) * g[:, :, :, :, None]
-    return (
-        dx.reshape(bn, oh, ow, c, k, k)
-        .transpose(0, 1, 4, 2, 5, 3)
-        .reshape(bn, h, w, c)
+    taken = jnp.zeros(y.shape, jnp.bool_)
+    rows = []
+    for a in range(k):
+        taps = []
+        for b in range(k):
+            eq = xw[:, :, a, :, b, :] == y
+            taps.append(jnp.where(eq & ~taken, g, 0.0))
+            taken = taken | eq
+        rows.append(jnp.stack(taps, axis=3))  # (bn, oh, ow, k, c)
+    return jnp.stack(rows, axis=2).reshape(bn, h, w, c)
+
+
+def _flatten_tile(x):
+    """(bn, h, w, c) -> (bn, h*w*c) in row-major order, pixel by pixel.
+
+    Mosaic cannot shape-cast the tiled (w, c) minor dims into one lane
+    axis, but it can concatenate the pixels' channel vectors along it.
+    """
+    if x.ndim != 4:
+        return x.reshape(x.shape[0], -1)
+    _, h, w, _ = x.shape
+    return jnp.concatenate(
+        [x[:, i, j, :] for i in range(h) for j in range(w)], axis=-1
+    )
+
+
+def _unflatten_tile(g, shape):
+    """The inverse of :func:`_flatten_tile`: lane slices restacked."""
+    if len(shape) != 3:
+        return g.reshape((g.shape[0],) + tuple(shape))
+    h, w, c = shape
+    return jnp.stack(
+        [
+            jnp.stack(
+                [g[:, (i * w + j) * c : (i * w + j + 1) * c] for j in range(w)],
+                axis=1,
+            )
+            for i in range(h)
+        ],
+        axis=1,
     )
 
 
@@ -174,9 +257,7 @@ def _stage_flow(region, env):
             if isinstance(s, Conv2dSpec):
                 y = _conv_fwd_tile(x, env[st.param], s)
             elif isinstance(s, MatmulSpec):
-                y = jnp.dot(
-                    x, env[st.param], preferred_element_type=jnp.float32
-                )
+                y = _dot(x, env[st.param])
             elif isinstance(s, BiasSpec):
                 y = x + env[st.param]
             elif isinstance(s, ReluSpec):
@@ -184,7 +265,7 @@ def _stage_flow(region, env):
             elif isinstance(s, MaxPool2dSpec):
                 y = _pool_fwd_tile(x, s)
             elif isinstance(s, FlattenSpec):
-                y = x.reshape(x.shape[0], -1)
+                y = _flatten_tile(x)
             else:
                 raise TypeError(f"no fused fwd rule for {type(s).__name__}")
             env[st.out_edge] = y
@@ -193,9 +274,7 @@ def _stage_flow(region, env):
             if isinstance(s, Conv2dSpec):
                 d = _conv_dw_tile(env[st.in_edge], g, s)
             elif isinstance(s, MatmulSpec):
-                d = jnp.dot(
-                    env[st.in_edge].T, g, preferred_element_type=jnp.float32
-                )
+                d = _dot(env[st.in_edge].T, g)
             elif isinstance(s, BiasSpec):
                 d = g.reshape(-1, s.c).sum(axis=0)
             else:
@@ -216,9 +295,7 @@ def _stage_flow(region, env):
             if isinstance(s, Conv2dSpec):
                 dx = _conv_dx_tile(g, env[st.param], s)
             elif isinstance(s, MatmulSpec):
-                dx = jnp.dot(
-                    g, env[st.param].T, preferred_element_type=jnp.float32
-                )
+                dx = _dot(g, env[st.param].T)
             elif isinstance(s, ReluSpec):
                 # mask from the relu *output*: y > 0 iff x > 0, so the
                 # pre-activation never has to escape its forward region
@@ -226,7 +303,7 @@ def _stage_flow(region, env):
             elif isinstance(s, MaxPool2dSpec):
                 dx = _pool_dx_tile(env[st.in_edge], g, s)
             elif isinstance(s, FlattenSpec):
-                dx = g.reshape((g.shape[0],) + tuple(s.in_shape))
+                dx = _unflatten_tile(g, s.in_shape)
             elif isinstance(s, BiasSpec):
                 dx = g
             else:
@@ -279,10 +356,6 @@ def build_region_callable(region, *, interpret: bool):
     n_s, n_r, n_o = len(streamed), len(resident), len(out_names)
 
     def fn(j):
-        B = region.batch
-        bn = _batch_block(B)
-        grid = B // bn
-
         def probe(vals):
             env, partials = _stage_flow(region, dict(vals))
             return env, partials
@@ -297,12 +370,21 @@ def build_region_callable(region, *, interpret: bool):
                 return jax.ShapeDtypeStruct(j[base].shape, jnp.float32)
             return env_sh[name]
 
-        in_specs = [pl.BlockSpec(memory_space=pltpu.ANY) for _ in streamed]
+        B = region.batch
+        bn = _batch_block(
+            B,
+            [len(j[n].shape) for n in streamed]
+            + [len(out_struct(n).shape) for n in batched_outs],
+        )
+        grid = B // bn
+
+        in_specs = []
+        for name in streamed:
+            shp = tuple(j[name].shape)
+            in_specs.append(pl.BlockSpec((bn,) + shp[1:], _lead_map(len(shp))))
         for name in resident:
             shp = tuple(j[name].shape)
-            in_specs.append(
-                pl.BlockSpec(shp, _const_map(len(shp)))
-            )
+            in_specs.append(pl.BlockSpec(shp, _const_map(len(shp))))
         out_specs, out_shape = [], []
         for name in batched_outs:
             shp = tuple(out_struct(name).shape)
@@ -316,52 +398,18 @@ def build_region_callable(region, *, interpret: bool):
             out_shape.append(jax.ShapeDtypeStruct(shp, jnp.float32))
 
         scratch = [
-            pltpu.VMEM((N_BUFFERS, bn) + tuple(j[name].shape[1:]), jnp.float32)
-            for name in streamed
-        ]
-        if n_s:
-            scratch.append(pltpu.SemaphoreType.DMA((N_BUFFERS, n_s)))
-        scratch += [
             pltpu.VMEM(tuple(part_sh[name].shape), jnp.float32)
             for name in acc_names
         ]
 
         def kernel(*refs):
-            srefs = refs[:n_s]
-            rrefs = refs[n_s : n_s + n_r]
+            irefs = refs[: n_s + n_r]
             orefs = refs[n_s + n_r : n_s + n_r + n_o]
-            rest = refs[n_s + n_r + n_o :]
-            bufs = rest[:n_s]
-            sem = rest[n_s] if n_s else None
-            accs = rest[n_s + (1 if n_s else 0) :]
-
+            accs = refs[n_s + n_r + n_o :]
             gi = pl.program_id(0)
-            slot = jax.lax.rem(gi, N_BUFFERS)
 
-            def copy_in(k, sl, idx):
-                return pltpu.make_async_copy(
-                    srefs[k].at[pl.ds(idx * bn, bn)],
-                    bufs[k].at[sl],
-                    sem.at[sl, k],
-                )
-
-            @pl.when(gi == 0)
-            def _():
-                for k in range(n_s):
-                    copy_in(k, 0, 0).start()
-
-            for k in range(n_s):
-                copy_in(k, slot, gi).wait()
-
-            @pl.when(gi + 1 < grid)
-            def _():
-                nxt = jax.lax.rem(gi + 1, N_BUFFERS)
-                for k in range(n_s):
-                    copy_in(k, nxt, gi + 1).start()
-
-            env = {name: rrefs[i][...] for i, name in enumerate(resident)}
-            for k, name in enumerate(streamed):
-                env[name] = bufs[k][slot]
+            names = streamed + resident
+            env = {name: irefs[i][...] for i, name in enumerate(names)}
             env, partials = _stage_flow(region, env)
 
             if acc_names:
@@ -396,6 +444,12 @@ def build_region_callable(region, *, interpret: bool):
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=scratch,
+            # the dW accumulators carry across batch tiles, so the grid
+            # runs in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES,
+            ),
             interpret=interpret,
         )(*[j[n] for n in streamed], *[j[n] for n in resident])
         if not isinstance(res, (list, tuple)):

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.precision import two_sum
 from repro.core.tiling import plan_matmul_tiles
 
@@ -103,7 +102,7 @@ def ntx_matmul(
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, bn), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -28,6 +28,10 @@ N_BUFFERS = 2  # double buffering, as in runtime.dma.DmaConfig
 
 
 def _stream_mm_kernel(a_hbm, b_hbm, o_ref, *, bm, bn, bk, k_tiles, a_dtype, b_dtype):
+    # f32 operands contract at full fp32 on the MXU; the default would
+    # round them to bf16 passes on TPU
+    precision = jax.lax.Precision.HIGHEST if a_dtype == jnp.float32 else None
+
     i = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -64,7 +68,8 @@ def _stream_mm_kernel(a_hbm, b_hbm, o_ref, *, bm, bn, bk, k_tiles, a_dtype, b_dt
 
             wait(cur, kk)
             acc_ref[...] += jnp.dot(
-                a_buf[cur], b_buf[cur], preferred_element_type=jnp.float32
+                a_buf[cur], b_buf[cur], preferred_element_type=jnp.float32,
+                precision=precision,
             )
             return carry
 
@@ -81,8 +86,32 @@ def _stream_mm_kernel(a_hbm, b_hbm, o_ref, *, bm, bn, bk, k_tiles, a_dtype, b_dt
     )
 
 
-def _block(dim: int, cap: int = 128) -> int:
-    return min(cap, 1 << (dim - 1).bit_length()) if dim < cap else cap
+LANE = 128  # TPU vreg lanes: the minor dim of every VMEM tile
+
+
+def _sublane(itemsize: int) -> int:
+    """Rows of one (sublane, 128) VMEM tile: 8 for 32-bit, 16 for 16-bit."""
+    return 8 * max(1, 4 // itemsize)
+
+
+def _block(dim: int, align: int) -> int:
+    """Tile edge: 128, or the next power of two at or above ``dim`` but
+    never below ``align`` — Mosaic slices HBM only in whole tiles, so a
+    small dim pads up to one tile and the result is sliced back."""
+    return LANE if dim >= LANE else max(align, 1 << (dim - 1).bit_length())
+
+
+def _blocks(m: int, n: int, k: int, sub: int, block_m, block_n, block_k):
+    """(bm, bn, bk): rows on the sublane tiling, N and K on whole lanes.
+
+    K is A's minor (lane) dim and N is B's, so both need ``LANE``-wide
+    tiles; M only needs whole sublane groups.
+    """
+    return (
+        block_m or _block(m, sub),
+        block_n or _block(n, LANE),
+        block_k or _block(k, LANE),
+    )
 
 
 def _pad_to(x: jnp.ndarray, mult: tuple[int, int]) -> jnp.ndarray:
@@ -107,9 +136,7 @@ def streaming_matmul(
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
-    bm = block_m or _block(m)
-    bn = block_n or _block(n)
-    bk = block_k or _block(k)
+    bm, bn, bk = _blocks(m, n, k, _sublane(a.dtype.itemsize), block_m, block_n, block_k)
     ap = _pad_to(a, (bm, bk))
     bp = _pad_to(b, (bk, bn))
     mp, kp = ap.shape
@@ -124,8 +151,8 @@ def streaming_matmul(
         kernel,
         grid=(mp // bm, np_ // bn),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
@@ -185,9 +212,7 @@ def streaming_tiles(
     :class:`repro.runtime.dma.DmaEngine` (or wrapping each entry in an
     ``NtxCommand``) yields the runtime's cycle estimate for this kernel.
     """
-    bm = block_m or _block(m)
-    bn = block_n or _block(n)
-    bk = block_k or _block(k)
+    bm, bn, bk = _blocks(m, n, k, _sublane(itemsize), block_m, block_n, block_k)
     mp, np_, kp = -(-m // bm) * bm, -(-n // bn) * bn, -(-k // bk) * bk
     tiles = []
     for _i in range(mp // bm):

@@ -22,6 +22,8 @@ from __future__ import annotations
 
 import functools
 import math
+import os
+from pathlib import Path
 from typing import Any
 
 import jax
@@ -328,14 +330,33 @@ def batch_shardings(batch_struct, cfg, mesh, dp_axes, seq_axis=None, batch_size=
 # ---------------------------------------------------------------------------
 
 
+def use_compile_cache() -> str:
+    """Point JAX's persistent compile cache at a fixed directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and this
+    sets nothing. Otherwise the cache goes to ``.jax_cache`` at the root of
+    the checkout (git-ignored): a path that never moves, so one run's
+    compiled steps are found again by the next. Entry points call this
+    before they compile; importing the library never does. Returns the
+    directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def validate_mesh_args(mesh: str | None, shard: str, batch: int) -> tuple[int, int] | None:
     """Upfront --mesh / --shard validation with actionable errors.
 
     Checks everything that would otherwise surface as a deep shard_map or
     splitter failure: mesh spec parses as RxC, the mesh is not degenerate,
     the batch divides over the cubes, and ``--shard 2d`` actually has a
-    mesh to shard over. Device-count shortfall is only a warning — the
-    executor falls back to the bit-identical single-device walk.
+    mesh to shard over. Device-count shortfall is only a note here: the
+    interpreter walks the mesh program on one device, and compiled
+    kernels refuse it in ``run_pallas``.
 
     Returns (rows, cols), or None when no mesh was requested.
     """
@@ -369,8 +390,9 @@ def validate_mesh_args(mesh: str | None, shard: str, batch: int) -> tuple[int, i
         )
     n_dev = jax.device_count()
     if n_dev < n:
-        print(f"note: {n_dev} jax device(s) < {n} cubes — run_pallas will "
-              f"use the (bit-identical) single-device walk; set "
+        print(f"note: {n_dev} jax device(s) < {n} cubes — only the Pallas "
+              f"interpreter walks the mesh program on one device (compiled "
+              f"kernels refuse it); set "
               f"XLA_FLAGS=--xla_force_host_platform_device_count={n} "
               f"for real shard_map execution")
     return rows, cols
@@ -463,7 +485,7 @@ def run_ntx_cnn(steps: int, batch: int, img: int, *, n_clusters: int = 16,
             n_dev = jax.device_count()
             how = ("shard_map data-parallel" if n_dev >= sharded.n_hmcs
                    else f"single-device walk ({n_dev} jax device(s) "
-                        f"< {sharded.n_hmcs} HMCs)")
+                        f"< {sharded.n_hmcs} HMCs; interpreter only)")
             print(f"mesh {sharded.mesh_shape[0]}x{sharded.mesh_shape[1]}: "
                   f"{sharded.n_hmcs} HMCs x {sharded.shard_batch} images, "
                   f"{len(program.blocks)} blocks incl. allreduce epilogue; "
@@ -567,8 +589,10 @@ def run_ntx_cnn(steps: int, batch: int, img: int, *, n_clusters: int = 16,
     return res
 
 
-def _dag_oracle_loss(graph, p, x, onehot):
-    """Any DAG NetworkGraph in plain jax — the ``--check-grads`` oracle."""
+def _dag_oracle_logits(graph, p, x, relu_masks=None):
+    """Any DAG NetworkGraph's forward in plain jax — the ``--check-grads``
+    oracle. ``relu_masks`` (node name -> bool array) fixes which side of
+    the kink each ReLU element takes, for value and derivative alike."""
     from repro.lower import (
         AttentionSpec,
         EmbeddingSpec,
@@ -586,7 +610,10 @@ def _dag_oracle_loss(graph, p, x, onehot):
         if isinstance(s, (MatmulSpec, EmbeddingSpec)):
             y = a @ p[node.param]
         elif isinstance(s, ReluSpec):
-            y = jax.nn.relu(a)
+            if relu_masks is None:
+                y = jax.nn.relu(a)
+            else:
+                y = jnp.where(relu_masks[node.name], a, 0.0)
         elif isinstance(s, LayerNormSpec):
             mu = jnp.mean(a, axis=-1, keepdims=True)
             var = jnp.mean((a - mu) ** 2, axis=-1, keepdims=True)
@@ -617,8 +644,106 @@ def _dag_oracle_loss(graph, p, x, onehot):
         else:  # pragma: no cover - new node types need an oracle rule
             raise TypeError(type(s).__name__)
         acts[node.out_edge] = y
-    z = acts[graph.logits_edge]
+    return acts[graph.logits_edge]
+
+
+def _dag_oracle_loss(graph, p, x, onehot, relu_masks=None):
+    """Softmax-CE of :func:`_dag_oracle_logits` — the gradient oracle."""
+    z = _dag_oracle_logits(graph, p, x, relu_masks)
     return -jnp.mean(jnp.sum(jax.nn.log_softmax(z) * onehot, axis=1))
+
+
+def _pallas_step_with_relu_masks(program, inputs, *, cache, fuse, interpret):
+    """One train step through the Pallas plans, plus the mask ``y > 0`` of
+    every ReLU forward it ran, by node name, from that same execution."""
+    from repro.lower import ReluSpec, executors
+
+    graph = program.meta["graph"]
+    interpret = executors._resolve_interpret(interpret)
+    fusion = executors._fusion_for(program, fuse_updates=True) if fuse else None
+    base = executors._dispatch_plan(cache, program.design.name, interpret)
+
+    def step(j):
+        masks = []
+
+        def plan(spec, pass_):
+            fn = base(spec, pass_)
+            if pass_ != "fwd" or not isinstance(spec, ReluSpec):
+                return fn
+
+            def recorded(ins):
+                out = fn(ins)
+                masks.append(out["y"] > 0.0)
+                return out
+
+            return recorded
+
+        outs = executors._graph_step_local(
+            graph, j, plan, graph.batch, keep_grads=True, fusion=fusion
+        )
+        return outs, masks
+
+    outs, masks = jax.jit(step)(executors._as_jax_f32(inputs))
+    names = [n.name for n in graph.nodes if isinstance(n.spec, ReluSpec)]
+    if len(masks) != len(names):  # a ReLU ran inside a fused region
+        raise ValueError(f"{len(masks)} ReLU forwards seen for {len(names)} ReLU nodes")
+    return outs, dict(zip(names, masks))
+
+
+def _check_lm_grads(graph, program, batch_fn, vocab, *, cache, fuse,
+                    interpret) -> dict:
+    """Re-run one step from the initial params and hold its logits to
+    :func:`_dag_oracle_logits` and every ``d_<param>`` to ``jax.grad`` of
+    :func:`_dag_oracle_loss`, elementwise at fp32 tolerance.
+
+    The gradient oracle takes each ReLU element on the side of the kink the
+    step's own forward took. Two fp32 evaluations of a deep ReLU stack put
+    a few of its tens of millions of pre-activations on opposite sides of
+    zero, and each such flip moves a whole gradient column by one row's
+    contribution — a full-width 24-layer step and the same oracle on
+    another backend disagree that way on about a tenth of the params. The
+    forward check, against the oracle's own ReLUs, still catches a wrong
+    mask. The oracle runs under ``jax.default_matmul_precision("highest")``:
+    a TPU's default f32 matmul rounds to bf16 passes, which is not the fp32
+    reference. Raises SystemExit on the first mismatch; returns the worst
+    relative gradient error and the number of params checked.
+    """
+    import numpy as np
+
+    from repro.lower import one_hot_rows
+
+    x, labels = batch_fn(0)
+    onehot = one_hot_rows(labels, vocab)
+    params = graph.init_params(seed=0)
+    inputs = {graph.input_edge: x, graph.label_edge: onehot, **params}
+    outs, masks = _pallas_step_with_relu_masks(
+        program, inputs, cache=cache, fuse=fuse, interpret=interpret
+    )
+    # only the logits and gradients are compared: drop the rest of the
+    # step's outputs before the oracle needs device memory
+    got_logits = np.asarray(outs[graph.logits_edge])
+    got_grads = {p: outs[f"d_{p}"] for p in graph.param_shapes()}
+    del outs
+    with jax.default_matmul_precision("highest"):
+        want_logits = np.asarray(jax.jit(
+            lambda p, x: _dag_oracle_logits(graph, p, x)
+        )(params, x))
+        want_grads = jax.jit(jax.grad(
+            lambda p, x, y, m: _dag_oracle_loss(graph, p, x, y, m)
+        ))(params, x, onehot, masks)
+    if not np.allclose(got_logits, want_logits, rtol=1e-4, atol=1e-5):
+        raise SystemExit("forward check FAILED: logits differ from the oracle")
+    worst = 0.0
+    for p in graph.param_shapes():
+        got = np.asarray(got_grads.pop(p))
+        want = np.asarray(want_grads.pop(p))
+        rel = float(np.max(np.abs(got - want)) / (np.max(np.abs(want)) + 1e-12))
+        worst = max(worst, rel)
+        if not np.allclose(got, want, rtol=1e-4, atol=1e-5):
+            raise SystemExit(f"gradient check FAILED for {p}: rel err {rel:.2e}")
+    n = len(graph.param_shapes())
+    print(f"gradient check vs jax.grad: {n} params OK (worst rel err {worst:.2e})")
+    return {"worst_rel_err": worst, "n_params": n}
 
 
 def run_ntx_lm(model: str, steps: int, batch: int, seq: int, *,
@@ -649,7 +774,8 @@ def run_ntx_lm(model: str, steps: int, batch: int, seq: int, *,
 
     ``check_grads`` re-runs one step and verifies every ``d_<param>``
     against ``jax.grad`` of the plain-jax graph oracle at fp32 tolerance —
-    the CI lm-train-smoke gate.
+    the CI lm-train-smoke gate; the result then carries ``"grad_check"``
+    (worst relative error, params checked).
     """
     from contextlib import nullcontext
 
@@ -662,7 +788,6 @@ def run_ntx_lm(model: str, steps: int, batch: int, seq: int, *,
         PlanCache,
         lm_token_batches,
         lower_training_step,
-        run_pallas,
         run_timing,
         shard_training_step,
         train_graph,
@@ -705,7 +830,7 @@ def run_ntx_lm(model: str, steps: int, batch: int, seq: int, *,
             n_dev = jax.device_count()
             how = ("shard_map data-parallel" if n_dev >= sharded.n_hmcs
                    else f"single-device walk ({n_dev} jax device(s) "
-                        f"< {sharded.n_hmcs} HMCs)")
+                        f"< {sharded.n_hmcs} HMCs; interpreter only)")
             print(f"mesh {sharded.mesh_shape[0]}x{sharded.mesh_shape[1]}: "
                   f"{sharded.n_hmcs} HMCs x {sharded.shard_batch} sequences, "
                   f"{len(program.blocks)} blocks incl. allreduce epilogue; "
@@ -755,32 +880,10 @@ def run_ntx_lm(model: str, steps: int, batch: int, seq: int, *,
     else:
         print("fusion: disabled (--no-fuse) — per-node plan dispatch")
     if check_grads:
-        x, labels = batch_fn(0)
-        eye = np.eye(cfg.vocab_size, dtype=np.float32)
-        onehot = eye[np.asarray(labels)]
-        params = graph.init_params(seed=0)
-        inputs = {graph.input_edge: x, graph.label_edge: onehot, **params}
-        outs = run_pallas(res["program"], inputs, cache=cache, fuse=fuse)
-        jp = {k: jnp.asarray(v) for k, v in params.items()}
-        grads = jax.grad(
-            lambda p: _dag_oracle_loss(graph, p, jnp.asarray(x),
-                                       jnp.asarray(onehot))
-        )(jp)
-        import numpy as _np
-
-        worst = 0.0
-        for p in graph.param_shapes():
-            got = _np.asarray(outs[f"d_{p}"])
-            want = _np.asarray(grads[p])
-            rel = float(_np.max(_np.abs(got - want))
-                        / (_np.max(_np.abs(want)) + 1e-12))
-            worst = max(worst, rel)
-            if not _np.allclose(got, want, rtol=1e-4, atol=1e-5):
-                raise SystemExit(
-                    f"gradient check FAILED for {p}: rel err {rel:.2e}"
-                )
-        print(f"gradient check vs jax.grad: {len(graph.param_shapes())} "
-              f"params OK (worst rel err {worst:.2e})")
+        res["grad_check"] = _check_lm_grads(
+            graph, res["program"], batch_fn, cfg.vocab_size,
+            cache=cache, fuse=fuse, interpret=interpret,
+        )
     if metrics:
         print(f"per-step metrics JSONL: {metrics}")
     if registry is not None:
@@ -793,6 +896,8 @@ def run_ntx_lm(model: str, steps: int, batch: int, seq: int, *,
 def _cli():
     import argparse
     import time
+
+    use_compile_cache()
 
     from repro.configs import get_config, reduce_config
     from repro.data.pipeline import DataIterator, InMemoryDataset
@@ -852,7 +957,9 @@ def _cli():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--optimizer", default="adamw", choices=["sgd", "adamw"])
-    ap.add_argument("--lr", type=float, default=3e-3)
+    ap.add_argument("--lr", type=float, default=None,
+                    help="learning rate (default: 3e-3 for the xla AdamW "
+                         "driver, 0.05 for ntx SGD)")
     ap.add_argument("--grad-sync", default="auto",
                     choices=["auto", "systolic", "compressed"])
     ap.add_argument("--microbatches", type=int, default=1)
@@ -879,13 +986,14 @@ def _cli():
 
     if args.backend == "ntx":
         validate_mesh_args(args.mesh, args.shard, args.batch)
+        lr = 0.05 if args.lr is None else args.lr
         if args.model is not None:
             if args.chaos is not None:
                 raise SystemExit("--chaos is CNN-path only for now; "
                                  "drop it or drop --model")
             res = run_ntx_lm(args.model, args.steps, args.batch, args.seq,
                              n_clusters=args.offload_clusters,
-                             lr=args.lr, reduced=args.reduced,
+                             lr=lr, reduced=args.reduced,
                              mesh=args.mesh, shard=args.shard,
                              metrics=args.metrics, trace=args.trace,
                              fuse=not args.no_fuse,
@@ -894,7 +1002,7 @@ def _cli():
                     and not res["losses"][-1] < res["losses"][0]):
                 raise SystemExit("ntx LM training did not decrease the loss")
             return
-        res = run_ntx_cnn(args.steps, args.batch, args.img,
+        res = run_ntx_cnn(args.steps, args.batch, args.img, lr=lr,
                           n_clusters=args.offload_clusters, mesh=args.mesh,
                           shard=args.shard,
                           metrics=args.metrics, trace=args.trace,
@@ -921,7 +1029,7 @@ def _cli():
                       tp_axis="model" if mesh else None, attn_backend="xla",
                       grad_sync=args.grad_sync)
 
-    opt = get_optimizer(args.optimizer, args.lr)
+    opt = get_optimizer(args.optimizer, 3e-3 if args.lr is None else args.lr)
     ds = InMemoryDataset.synthetic(2_000_000, cfg.vocab_size, args.seq, seed=0)
     iterator = DataIterator(ds, batch_size=args.batch, seed=0)
 

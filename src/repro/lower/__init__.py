@@ -53,6 +53,7 @@ from repro.lower.graph import (
     frequency_band_batches,
     lm_token_batches,
     lower_training_step,
+    one_hot_rows,
     paper_cnn_graph,
     softmax_xent_loss,
     train_graph,
@@ -137,6 +138,7 @@ __all__ = [
     "lower",
     "lower_layer",
     "lower_training_step",
+    "one_hot_rows",
     "paper_cnn_graph",
     "register_lowering",
     "softmax_xent_loss",

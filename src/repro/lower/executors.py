@@ -50,11 +50,13 @@ from repro.lower.rules import (
 
 @dataclass(frozen=True)
 class BatchedSpec:
-    """A per-image spec vmapped over the leading batch axis.
+    """A per-image spec run over the leading batch axis.
 
     The graph executor uses this as the plan-cache key for per-image layer
-    nodes (conv/pool) executing over a whole batch: parameters broadcast,
-    everything else maps over axis 0.
+    nodes (conv/pool) executing over a whole batch. Convs fold the images
+    into the streamed matmul rows, and their dW comes out summed over the
+    batch; other specs vmap, with parameters broadcast and everything else
+    mapped over axis 0.
     """
 
     spec: object
@@ -142,6 +144,64 @@ def run_timing(
 # ---------------------------------------------------------------------------
 
 
+def _conv_plan(spec: Conv2dSpec, pass_: str, interpret: bool):
+    """One conv pass over a batch of images (N, H, W, C) in the streaming
+    kernel, or None for a pass the conv has no route for.
+
+    The images fold into the matmul rows, so dW comes out summed over the
+    batch (the images join the contraction, as in the fused region).
+    """
+    import jax.numpy as jnp
+
+    from repro.core import conv_decomp
+    from repro.kernels import streaming
+
+    s, p = spec.stride, spec.padding
+    if pass_ == "fwd":
+        return lambda j: {
+            "y": streaming.streaming_conv2d(
+                j["x"], j["w"], stride=s, padding=p, interpret=interpret
+            )
+        }
+    if pass_ == "dw":
+        # dW = cols(x)^T @ dy: the same im2col the forward kernel streams,
+        # with the (n*oh*ow) output pixels as the contraction dim.
+        def dw(j):
+            x = j["x"]
+            if p:
+                x = jnp.pad(x, ((0, 0), (p, p), (p, p), (0, 0)))
+            oh, ow = spec.out_h, spec.out_w
+            cols = jnp.concatenate(
+                [
+                    x[:, dh : dh + oh * s : s, dw_ : dw_ + ow * s : s, :]
+                    for dh in range(spec.kh)
+                    for dw_ in range(spec.kw)
+                ],
+                axis=-1,
+            ).reshape(-1, spec.kh * spec.kw * spec.cin)
+            dyf = j["dy"].reshape(-1, spec.cout)
+            dw_flat = streaming.streaming_matmul(cols.T, dyf, interpret=interpret)
+            return {"dw": dw_flat.reshape(spec.kh, spec.kw, spec.cin, spec.cout)}
+
+        return dw
+    if pass_ == "dx":
+        # The §3.2 phase decomposition with the dense per-phase conv
+        # routed through the streaming Pallas kernel.
+        def conv_fn(dy, w_ab, pads):
+            ph, pw = pads
+            dyp = jnp.pad(dy, ((0, 0), (ph, ph), (pw, pw), (0, 0)))
+            return streaming.streaming_conv2d(
+                dyp, w_ab, stride=1, padding=0, interpret=interpret
+            )
+
+        return lambda j: {
+            "dx": conv_decomp.conv2d_input_grad_decomposed(
+                j["dy"], j["w"], s, (spec.in_h, spec.in_w), p, conv_fn=conv_fn
+            )
+        }
+    return None
+
+
 def _plan_callable(spec, pass_: str, interpret: bool):
     """Pure jax function dict[str, Array] -> dict[str, Array] for one plan.
 
@@ -152,7 +212,6 @@ def _plan_callable(spec, pass_: str, interpret: bool):
     import jax
     import jax.numpy as jnp
 
-    from repro.core import conv_decomp
     from repro.kernels import streaming
     from repro.lower.fuse import RegionSpec
 
@@ -177,61 +236,15 @@ def _plan_callable(spec, pass_: str, interpret: bool):
             }
 
     if isinstance(spec, Conv2dSpec):
-        s, p = spec.stride, spec.padding
-        if pass_ == "fwd":
+        conv = _conv_plan(spec, pass_, interpret)
+        if conv is not None:
+            # one image: lend it a batch axis of one and take it back off
+            # (dW has no batch axis to remove)
+            def one_image(j):
+                out = conv({k: v if k == "w" else v[None] for k, v in j.items()})
+                return {k: v if k == "dw" else v[0] for k, v in out.items()}
 
-            def fwd(j):
-                y = streaming.streaming_conv2d(
-                    j["x"][None], j["w"], stride=s, padding=p, interpret=interpret
-                )
-                return {"y": y[0]}
-
-            return fwd
-        if pass_ == "dw":
-            # dW = cols(x)^T @ dy: the same im2col the forward kernel streams,
-            # with the (oh*ow) output pixels as the contraction dim.
-            def dw(j):
-                x = j["x"][None]
-                if p:
-                    xp = jnp.pad(x, ((0, 0), (p, p), (p, p), (0, 0)))
-                else:
-                    xp = x
-                oh, ow = spec.out_h, spec.out_w
-                cols = jnp.concatenate(
-                    [
-                        xp[:, dh : dh + oh * s : s, dw_ : dw_ + ow * s : s, :]
-                        for dh in range(spec.kh)
-                        for dw_ in range(spec.kw)
-                    ],
-                    axis=-1,
-                ).reshape(oh * ow, spec.kh * spec.kw * spec.cin)
-                dyf = j["dy"].reshape(oh * ow, spec.cout)
-                dw_flat = streaming.streaming_matmul(
-                    cols.T, dyf, interpret=interpret
-                )
-                return {
-                    "dw": dw_flat.reshape(spec.kh, spec.kw, spec.cin, spec.cout)
-                }
-
-            return dw
-        if pass_ == "dx":
-            # The §3.2 phase decomposition with the dense per-phase conv
-            # routed through the streaming Pallas kernel.
-            def dx(j):
-                def conv_fn(dy, w_ab, pads):
-                    ph, pw = pads
-                    dyp = jnp.pad(dy, ((0, 0), (ph, ph), (pw, pw), (0, 0)))
-                    return streaming.streaming_conv2d(
-                        dyp, w_ab, stride=1, padding=0, interpret=interpret
-                    )
-
-                out = conv_decomp.conv2d_input_grad_decomposed(
-                    j["dy"][None], j["w"], s, (spec.in_h, spec.in_w), p,
-                    conv_fn=conv_fn,
-                )
-                return {"dx": out[0]}
-
-            return dx
+            return one_image
 
     if isinstance(spec, MaxPool2dSpec):
         w, s = spec.window, spec.stride
@@ -296,12 +309,14 @@ def _plan_callable(spec, pass_: str, interpret: bool):
             q = x[:, :D].reshape(S, H, Dh).transpose(1, 0, 2)
             k = x[:, D:2 * D].reshape(S, H, Dh).transpose(1, 0, 2)
             v = x[:, 2 * D:].reshape(S, H, Dh).transpose(1, 0, 2)
-            sc = jnp.einsum("hid,hjd->hij", q, k) * spec.scale
+            # full fp32 contractions: the TPU default rounds f32 to bf16
+            hi = jax.lax.Precision.HIGHEST
+            sc = jnp.einsum("hid,hjd->hij", q, k, precision=hi) * spec.scale
             mask = jnp.where(
                 jnp.tril(jnp.ones((S, S), x.dtype)) > 0, 0.0, -1e9
             )
             p = jax.nn.softmax(sc + mask[None], axis=-1)
-            ctx = jnp.einsum("hij,hjd->hid", p, v)
+            ctx = jnp.einsum("hij,hjd->hid", p, v, precision=hi)
             return ctx.transpose(1, 0, 2).reshape(S, D)
 
         if pass_ == "fwd":
@@ -372,6 +387,13 @@ def _plan_callable(spec, pass_: str, interpret: bool):
             return lambda j: {"dx": j["dy"]}
 
     if isinstance(spec, BatchedSpec):
+        if isinstance(spec.spec, Conv2dSpec):
+            # the images fold into the streamed matmul rows: a vmap would
+            # hand the ANY-space streaming kernel a squeezed batch block,
+            # which Mosaic refuses
+            conv = _conv_plan(spec.spec, pass_, interpret)
+            if conv is not None:
+                return conv
         inner = _plan_callable(spec.spec, pass_, interpret)
 
         def batched(j):
@@ -789,8 +811,7 @@ def _graph_step_local(graph, j, plan, B, *, keep_grads=True,
         if node.param is not None:
             p = node.param
             if isinstance(s, Conv2dSpec):
-                dwv = plan(bspec(s), "dw")({"x": a_in, "dy": g})["dw"]
-                dw = dwv.sum(axis=0) if batched else dwv
+                dw = plan(bspec(s), "dw")({"x": a_in, "dy": g})["dw"]
             elif isinstance(s, MatmulSpec):
                 dw = plan(s, "dw")({"a": a_in, "dy": g})["dw"]
             elif isinstance(s, BiasSpec):
@@ -928,10 +949,9 @@ def _walk_fused(graph, j, plan, B, fusion, *, keep_grads, reduce, batched):
         elif pass_ == "dw":
             g = env[f"d_{node.out_edge}"]
             if isinstance(s, Conv2dSpec):
-                dwv = plan(bspec(s), "dw")(
+                dw = plan(bspec(s), "dw")(
                     {"x": env[node.in_edge], "dy": g}
                 )["dw"]
-                dw = dwv.sum(axis=0) if batched else dwv
             elif isinstance(s, MatmulSpec):
                 dw = plan(s, "dw")({"a": env[node.in_edge], "dy": g})["dw"]
             elif isinstance(s, BiasSpec):
@@ -1038,16 +1058,17 @@ def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
     cross-shard ``psum`` — a batch *mean* because the loss plan already
     scales by 1 / B_global. Updated weights come back replicated, exactly
     like the allgather of the command-level epilogue. With fewer jax
-    devices than HMCs the walk runs unsharded on the full batch — the same
-    numerics, minus the parallelism (the command-level program is
-    unaffected; only this executor degrades).
+    devices than HMCs, compiled kernels raise before any kernel runs; only
+    the interpreter (CPU tests) walks the mesh program unsharded on the
+    full batch — the same numerics, minus the parallelism.
 
     Elastically re-sharded programs (``mesh_meta["alive"]`` set by
     :func:`repro.lower.mesh.reshard_training_step`) re-enter ``shard_map``
     over a SHRUNKEN ``(1, n_alive)`` jax mesh — the survivors' batch
     shards, with the psum spanning only the shrunken mesh. When the batch
-    no longer divides the survivor count (uneven re-chunking) or too few
-    jax devices remain, the same single-device walk takes over.
+    no longer divides the survivor count (uneven re-chunking), the
+    program walks the full batch on one device (so does the interpreter
+    when too few jax devices remain).
 
     2D-sharded programs (``mesh_meta["shard"] == "2d"``) run over a real
     2D jax mesh with ``("pipe", "data")`` axes shaped like the physical
@@ -1081,6 +1102,12 @@ def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
     j = _as_jax_f32(inputs)
     plan = _dispatch_plan(cache, program.design.name, interpret)
 
+    if jax.device_count() < n_alive and not interpret:
+        raise RuntimeError(
+            f"mesh program needs {n_alive} jax devices, found "
+            f"{jax.device_count()}: compiled kernels never walk a mesh "
+            f"program on one device (only the interpreter does)"
+        )
     if jax.device_count() < n_alive or B % n_alive:
         fusion = _fusion_for(program, fuse_updates=True) if fuse else None
         _record_fusion(obs.get_active(), fusion)

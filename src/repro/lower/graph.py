@@ -180,6 +180,8 @@ class NetworkGraph:
 
     input_edge: str = "x"
     label_edge: str = "onehot"
+    #: std of the gaussian weight init (biases zero, norm gains one)
+    init_std: float = 0.1
 
     @classmethod
     def sequential(
@@ -329,10 +331,14 @@ class NetworkGraph:
             add(f"res2_{i}", ResidualAddSpec((rows, d)), aux=(skip,))
         add("lnf", LayerNormSpec(rows, d, eps))
         add("head", MatmulSpec(rows, V, d))
+        # weights start at the initializer range the published decoder
+        # configs use (Qwen1.5, Llama, GPT-2: 0.02). At 0.1 a full-width
+        # stack is chaotic: its fp32 and fp64 gradients already differ by
+        # ~1e-2, so no fp32 check can hold it to rtol 1e-4
         return cls(
             name=f"lm_{cfg.name}", batch=B, input_shape=(S, V), nodes=nodes,
             loss=SoftmaxXentSpec(batch=rows, classes=V),
-            lr=lr, momentum=momentum,
+            lr=lr, momentum=momentum, init_std=0.02,
         )
 
     # -- conveniences -------------------------------------------------------
@@ -361,7 +367,7 @@ class NetworkGraph:
             elif pname.startswith("b_"):
                 out[pname] = np.zeros(shape, np.float32)
             else:
-                out[pname] = (rng.randn(*shape) * 0.1).astype(np.float32)
+                out[pname] = (rng.randn(*shape) * self.init_std).astype(np.float32)
             if self.momentum:
                 out[f"v_{pname}"] = np.zeros(shape, np.float32)
         return out
@@ -1157,21 +1163,36 @@ def frequency_band_batches(
     return batch_fn
 
 
+def one_hot_rows(idx, n_classes: int) -> np.ndarray:
+    """float32 rows, one per index, with 1.0 at the index: ``eye[idx]``
+    without the (n_classes, n_classes) identity, which at a 150k-token
+    vocabulary would take 92 GB."""
+    idx = np.asarray(idx).reshape(-1)
+    rows = np.zeros((idx.size, n_classes), np.float32)
+    rows[np.arange(idx.size), idx] = 1.0
+    return rows
+
+
 def lm_token_batches(
     rng: np.random.RandomState, batch: int, seq: int, vocab: int
 ) -> Callable[[int], tuple[np.ndarray, np.ndarray]]:
     """Synthetic next-token task for the LM train-step drivers: every
     position's target is a fixed affine remap of its input token, so the
     mapping is learnable by embedding + head alone and a few SGD steps
-    visibly reduce the CE loss. Returns ``batch_fn(step) -> (one-hot
-    tokens (B*S, V) float32, target ids (B*S,) int)`` — the token-row
-    layout :meth:`NetworkGraph.from_model_config` graphs consume."""
-    eye = np.eye(vocab, dtype=np.float32)
+    visibly reduce the CE loss. Token ids follow Zipf's law (frequency of
+    rank r ~ 1/r), as natural-language tokens do: the frequent tokens recur
+    in every batch, so the map is learnable in a few steps at any
+    vocabulary size — uniform draws from a 150k vocabulary would almost
+    never repeat a token. Returns ``batch_fn(step) -> (one-hot tokens
+    (B*S, V) float32, target ids (B*S,) int)`` — the token-row layout
+    :meth:`NetworkGraph.from_model_config` graphs consume."""
+    freq = 1.0 / np.arange(1, vocab + 1)
+    freq /= freq.sum()
 
     def batch_fn(_step):
-        tok = rng.randint(0, vocab, batch * seq)
+        tok = rng.choice(vocab, batch * seq, p=freq)
         nxt = (tok * 3 + 1) % vocab
-        return eye[tok], nxt
+        return one_hot_rows(tok, vocab), nxt
 
     return batch_fn
 
@@ -1234,7 +1255,6 @@ def train_graph(
     if params is None:
         params = graph.init_params()
     params = dict(params)
-    eye = np.eye(graph.loss.classes, dtype=np.float32)
     losses: list[float] = []
     walls: list[float] = []
     reg = registry if registry is not None else obs_counters.get_active()
@@ -1253,7 +1273,8 @@ def train_graph(
                 t0 = _time.perf_counter()
                 x, labels = batch_fn(i)
                 inputs = {graph.input_edge: np.asarray(x, np.float32),
-                          graph.label_edge: eye[np.asarray(labels)], **params}
+                          graph.label_edge: one_hot_rows(labels, graph.loss.classes),
+                          **params}
                 step_scope = (
                     reg.scope(f"step{i}") if reg is not None else nullcontext()
                 )

@@ -184,7 +184,7 @@ def moe_ep(x: jnp.ndarray, params, cfg, mesh, dp_axes: tuple[str, ...] = ()):
     wd_spec = P("model", gather_axis, None)
     # When nested inside a manual region (the systolic train step), shard_map
     # must be given the surrounding *abstract* mesh, not the concrete one.
-    ctx_mesh = compat.get_abstract_mesh()
+    ctx_mesh = jax.sharding.get_abstract_mesh()
     sm_mesh = ctx_mesh if (ctx_mesh is not None and ctx_mesh.shape) else mesh
     out_spec = P(("model",) ,*( [dp_axes] if dp_axes else [None]), None)
     y = compat.shard_map(

@@ -8,36 +8,19 @@ import subprocess
 import sys
 from pathlib import Path
 
-import jax
 import pytest
 
 pytestmark = pytest.mark.slow  # each case is a fresh 8-fake-device subprocess
 
 ROOT = Path(__file__).resolve().parents[2]
 
-# jax 0.4.x's shard_map cannot partially-auto over a subset of mesh axes the
-# way these two cases need (fixed in the 0.5+ sharding-in-types rework, and
-# verified green on the CI matrix's 0.5.3 lane); they have failed since the
-# seed on 0.4.x. Gate on the parsed (major, minor) tuple rather than a
-# string prefix so e.g. "0.40.0" or a dev suffix can't dodge (or wrongly
-# trip) the guard — 0.5+ runs both cases for real.
-_JAX_MAJOR_MINOR = tuple(
-    int(p) for p in jax.__version__.split(".")[:2] if p.isdigit()
-)
-_PARTIAL_AUTO_XFAIL = pytest.mark.xfail(
-    condition=_JAX_MAJOR_MINOR < (0, 5),
-    reason=f"jax {jax.__version__}: partial-auto shard_map over a mesh-axis "
-    "subset is unsupported before 0.5 (green on >=0.5.3)",
-    strict=False,
-)
-
 CASES = [
     "systolic_equals_psum",
     "systolic_tree",
-    pytest.param("train_systolic_equals_auto", marks=_PARTIAL_AUTO_XFAIL),
+    "train_systolic_equals_auto",
     "moe_ep_multidevice_matches_dense",
     "elastic_checkpoint_reshard",
-    pytest.param("compressed_train_step_runs", marks=_PARTIAL_AUTO_XFAIL),
+    "compressed_train_step_runs",
     "sp_model_same_loss",
     # mesh-of-HMCs data parallelism: run_pallas on a sharded train-step
     # program vs jax.grad at 1, 4, and 16 simulated devices (each case

@@ -8,6 +8,8 @@ invariant (regions overlapping in time never overlap in address), and all
 three executors must see the same command stream.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -439,3 +441,79 @@ def test_liveness_allocator_unit():
     assert y.base == 40  # live regions were never moved
     f = l.alias("f", "z", (5, 7), "scratch", end=9)
     assert f.base == z.base and f.size == z.size
+
+
+def test_one_hot_rows_equal_identity_rows():
+    from repro.lower import one_hot_rows
+
+    idx = np.array([3, 0, 7, 3, 9])
+    rows = one_hot_rows(idx, 10)
+    assert rows.dtype == np.float32
+    np.testing.assert_array_equal(rows, np.eye(10, dtype=np.float32)[idx])
+
+
+def test_one_hot_rows_at_full_vocabulary():
+    """qwen1.5's 151936-token vocabulary, without a V x V identity."""
+    from repro.lower import one_hot_rows
+
+    V = 151936
+    idx = np.array([0, 151935, 42, 42])
+    rows = one_hot_rows(idx, V)
+    assert rows.shape == (4, V) and rows.dtype == np.float32
+    np.testing.assert_array_equal(rows.argmax(axis=1), idx)
+    np.testing.assert_array_equal(rows.sum(axis=1), np.ones(4, np.float32))
+
+
+def test_oracle_relu_masks_fix_the_kink_side():
+    """With each ReLU's own mask the oracle is unchanged; flipping one
+    element moves exactly that fc1 gradient column."""
+    from repro.launch.train import _dag_oracle_logits, _dag_oracle_loss
+    from repro.lower import ReluSpec, one_hot_rows
+
+    graph = _tiny_lm(n_layers=1)
+    params = {k: jnp.asarray(v) for k, v in graph.init_params(seed=1).items()}
+    rng = np.random.RandomState(8)
+    V = graph.loss.classes
+    x = one_hot_rows(rng.randint(0, V, graph.loss.batch), V)
+    onehot = one_hot_rows(rng.randint(0, V, graph.loss.batch), V)
+    relu = next(n for n in graph.nodes if isinstance(n.spec, ReluSpec))
+    # the ReLU input: the oracle's forward cut just before the ReLU
+    upto = dataclasses.replace(graph, nodes=graph.nodes[: graph.nodes.index(relu)])
+    own = {relu.name: _dag_oracle_logits(upto, params, x) > 0}
+    grad = jax.grad(lambda p, m: _dag_oracle_loss(graph, p, x, onehot, m))
+    g0 = jax.grad(lambda p: _dag_oracle_loss(graph, p, x, onehot))(params)
+    g1 = grad(params, own)
+    for p in graph.param_shapes():
+        np.testing.assert_allclose(g1[p], g0[p], rtol=1e-6, atol=1e-7, err_msg=p)
+    # flip the element nearest its kink: the forward barely moves, and the
+    # derivative change lands on that one fc1 gradient column
+    upto_z = np.abs(np.asarray(_dag_oracle_logits(upto, params, x)))
+    r, c = np.unravel_index(np.argmin(upto_z), upto_z.shape)
+    flipped = {relu.name: own[relu.name].at[r, c].set(~own[relu.name][r, c])}
+    g2 = grad(params, flipped)
+    fc1 = next(n.param for n in graph.nodes if n.out_edge == relu.in_edge)
+    moved = np.abs(np.asarray(g2[fc1]) - np.asarray(g1[fc1]))
+    assert moved[:, c].max() > 100 * np.delete(moved, c, axis=1).max()
+
+
+def test_lm_grad_check_uses_the_steps_own_relu_masks():
+    from repro.launch.train import _check_lm_grads, _pallas_step_with_relu_masks
+    from repro.lower import PlanCache, ReluSpec, lm_token_batches, run_pallas
+
+    graph = _tiny_lm()
+    prog = lower_training_step(graph)
+    V = graph.loss.classes
+    batch_fn = lm_token_batches(np.random.RandomState(0), graph.batch, 6, V)
+    cache = PlanCache()
+    res = _check_lm_grads(graph, prog, batch_fn, V, cache=cache, fuse=True, interpret=True)
+    assert res["n_params"] == len(graph.param_shapes()) and res["worst_rel_err"] < 1e-4
+
+    x, labels = batch_fn(1)
+    inputs = {"x": x, "onehot": np.eye(V, dtype=np.float32)[labels], **graph.init_params()}
+    outs, masks = _pallas_step_with_relu_masks(
+        prog, inputs, cache=cache, fuse=True, interpret=True
+    )
+    assert sorted(masks) == sorted(n.name for n in graph.nodes if isinstance(n.spec, ReluSpec))
+    want = run_pallas(prog, inputs, cache=cache, interpret=True)
+    for k in want:
+        np.testing.assert_allclose(np.asarray(outs[k]), np.asarray(want[k]), rtol=1e-5, atol=1e-6)

@@ -482,3 +482,19 @@ def test_2d_efficiency_executed_one_size():
     tm = time_mesh_step(sh)
     assert tm.parallel_eff >= 0.80
     assert tm.bubble_frac <= 0.25
+
+
+def test_compiled_mesh_program_refuses_too_few_devices():
+    """Compiled kernels never walk a mesh program on one device: with fewer
+    jax devices than HMCs, run_pallas raises before building any plan."""
+    import jax
+
+    from repro.lower import PlanCache, run_pallas
+
+    graph = paper_cnn_graph(batch=4, img=8, momentum=0.9)
+    sharded = shard_training_step(graph, mesh_shape=(2, 2))
+    assert jax.device_count() < sharded.n_hmcs
+    cache = PlanCache()
+    with pytest.raises(RuntimeError, match="needs 4 jax devices"):
+        run_pallas(sharded.program, _inputs(graph, seed=5), interpret=False, cache=cache)
+    assert len(cache) == 0
