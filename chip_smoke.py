@@ -79,13 +79,14 @@ def phase_lm(train, interpret: bool) -> None:
     print(f"phase A losses: {losses}")
     print(f"phase A step walls (s): {walls}")
     print(f"phase A worst grad rel err: {res['grad_check']['worst_rel_err']:.3e} "
-          f"over {res['grad_check']['n_params']} params")
+          f"over {res['grad_check']['n_params']} params; "
+          f"{res['grad_check']['relu_flips']} ReLU elements on the other side of the kink")
     print(f"phase A peak_bytes_in_use: {peak_bytes()}")
     print(f"phase A wall incl. set-up: {time.perf_counter() - t0:.1f} s")
     check(len(losses) == LM_STEPS, f"{len(losses)} LM losses")
     check(all(math.isfinite(v) for v in losses), f"non-finite LM loss {losses}")
-    # the same gate as the training CLI: the last step's loss is below the first
-    check(losses[-1] < losses[0], f"LM loss did not decrease {losses}")
+    check(all(b < a for a, b in zip(losses, losses[1:])),
+          f"LM loss did not decrease every step {losses}")
 
 
 def phase_cnn(train, interpret: bool) -> None:

@@ -589,10 +589,11 @@ def run_ntx_cnn(steps: int, batch: int, img: int, *, n_clusters: int = 16,
     return res
 
 
-def _dag_oracle_logits(graph, p, x, relu_masks=None):
+def _dag_oracle_acts(graph, p, x, relu_masks=None):
     """Any DAG NetworkGraph's forward in plain jax — the ``--check-grads``
-    oracle. ``relu_masks`` (node name -> bool array) fixes which side of
-    the kink each ReLU element takes, for value and derivative alike."""
+    oracle — as every edge's activation by edge name. ``relu_masks`` (node
+    name -> bool array) fixes which side of the kink each ReLU element
+    takes, for value and derivative alike."""
     from repro.lower import (
         AttentionSpec,
         EmbeddingSpec,
@@ -644,7 +645,12 @@ def _dag_oracle_logits(graph, p, x, relu_masks=None):
         else:  # pragma: no cover - new node types need an oracle rule
             raise TypeError(type(s).__name__)
         acts[node.out_edge] = y
-    return acts[graph.logits_edge]
+    return acts
+
+
+def _dag_oracle_logits(graph, p, x, relu_masks=None):
+    """The logits of :func:`_dag_oracle_acts`."""
+    return _dag_oracle_acts(graph, p, x, relu_masks)[graph.logits_edge]
 
 
 def _dag_oracle_loss(graph, p, x, onehot, relu_masks=None):
@@ -653,86 +659,83 @@ def _dag_oracle_loss(graph, p, x, onehot, relu_masks=None):
     return -jnp.mean(jnp.sum(jax.nn.log_softmax(z) * onehot, axis=1))
 
 
-def _pallas_step_with_relu_masks(program, inputs, *, cache, fuse, interpret):
-    """One train step through the Pallas plans, plus the mask ``y > 0`` of
-    every ReLU forward it ran, by node name, from that same execution."""
-    from repro.lower import ReluSpec, executors
-
-    graph = program.meta["graph"]
-    interpret = executors._resolve_interpret(interpret)
-    fusion = executors._fusion_for(program, fuse_updates=True) if fuse else None
-    base = executors._dispatch_plan(cache, program.design.name, interpret)
-
-    def step(j):
-        masks = []
-
-        def plan(spec, pass_):
-            fn = base(spec, pass_)
-            if pass_ != "fwd" or not isinstance(spec, ReluSpec):
-                return fn
-
-            def recorded(ins):
-                out = fn(ins)
-                masks.append(out["y"] > 0.0)
-                return out
-
-            return recorded
-
-        outs = executors._graph_step_local(
-            graph, j, plan, graph.batch, keep_grads=True, fusion=fusion
-        )
-        return outs, masks
-
-    outs, masks = jax.jit(step)(executors._as_jax_f32(inputs))
-    names = [n.name for n in graph.nodes if isinstance(n.spec, ReluSpec)]
-    if len(masks) != len(names):  # a ReLU ran inside a fused region
-        raise ValueError(f"{len(masks)} ReLU forwards seen for {len(names)} ReLU nodes")
-    return outs, dict(zip(names, masks))
-
-
 def _check_lm_grads(graph, program, batch_fn, vocab, *, cache, fuse,
                     interpret) -> dict:
-    """Re-run one step from the initial params and hold its logits to
-    :func:`_dag_oracle_logits` and every ``d_<param>`` to ``jax.grad`` of
-    :func:`_dag_oracle_loss`, elementwise at fp32 tolerance.
+    """Re-run one step of ``program`` from the initial params through
+    :func:`run_pallas` — the executor's own route, a mesh program's
+    ``shard_map`` and psum included — and hold it to the plain-jax oracle
+    at fp32 tolerance (rtol 1e-4, atol 1e-5), in this order:
 
-    The gradient oracle takes each ReLU element on the side of the kink the
-    step's own forward took. Two fp32 evaluations of a deep ReLU stack put
-    a few of its tens of millions of pre-activations on opposite sides of
-    zero, and each such flip moves a whole gradient column by one row's
-    contribution — a full-width 24-layer step and the same oracle on
-    another backend disagree that way on about a tenth of the params. The
-    forward check, against the oracle's own ReLUs, still catches a wrong
-    mask. The oracle runs under ``jax.default_matmul_precision("highest")``:
-    a TPU's default f32 matmul rounds to bf16 passes, which is not the fp32
+    1. ReLU sides. Where the step put a ReLU element on the other side of
+       the kink from the oracle's own forward, the oracle's pre-activation
+       must lie within rtol of zero, relative to the layer's largest
+       (``|a| <= 1e-4 * max|a|``): rounding, not a wrong mask.
+    2. The logits against the oracle's own forward.
+    3. Every ``d_<param>`` elementwise against ``jax.grad`` of
+       :func:`_dag_oracle_loss`, the oracle taking each ReLU element on
+       the step's side of the kink. Two fp32 evaluations of a deep ReLU
+       stack put a few of its tens of millions of pre-activations on
+       opposite sides of zero, and each such flip moves a whole gradient
+       column by one row's contribution — a full-width 24-layer step and
+       the same oracle on another backend disagree that way on about a
+       tenth of the params. Check 1 bounds where those flips may lie.
+
+    The oracle runs under ``jax.default_matmul_precision("highest")``: a
+    TPU's default f32 matmul rounds to bf16 passes, which is not the fp32
     reference. Raises SystemExit on the first mismatch; returns the worst
-    relative gradient error and the number of params checked.
+    relative gradient error, the number of params checked and the number
+    of ReLU elements on the other side of the kink.
     """
     import numpy as np
 
-    from repro.lower import one_hot_rows
+    from repro.lower import ReluSpec, one_hot_rows, run_pallas
 
     x, labels = batch_fn(0)
     onehot = one_hot_rows(labels, vocab)
     params = graph.init_params(seed=0)
     inputs = {graph.input_edge: x, graph.label_edge: onehot, **params}
-    outs, masks = _pallas_step_with_relu_masks(
-        program, inputs, cache=cache, fuse=fuse, interpret=interpret
-    )
+    outs = run_pallas(program, inputs, interpret=interpret, cache=cache,
+                      fuse=fuse, relu_outputs=True)
+    relus = [n for n in graph.nodes if isinstance(n.spec, ReluSpec)]
+    hidden = [n.name for n in relus if n.out_edge not in outs]
+    if hidden:
+        raise ValueError(f"ReLU outputs of {hidden} ran inside fused regions")
+    # on the host: a mesh step leaves them sharded over its devices
+    masks = {n.name: np.asarray(outs[n.out_edge]) > 0.0 for n in relus}
     # only the logits and gradients are compared: drop the rest of the
     # step's outputs before the oracle needs device memory
     got_logits = np.asarray(outs[graph.logits_edge])
     got_grads = {p: outs[f"d_{p}"] for p in graph.param_shapes()}
     del outs
+
+    def forward(p, x):
+        acts = _dag_oracle_acts(graph, p, x)
+        return acts[graph.logits_edge], {n.name: acts[n.in_edge] for n in relus}
+
     with jax.default_matmul_precision("highest"):
-        want_logits = np.asarray(jax.jit(
-            lambda p, x: _dag_oracle_logits(graph, p, x)
-        )(params, x))
+        want_logits, pre = jax.jit(forward)(params, x)
+    flips, total, worst_side = 0, 0, 0.0
+    for n in relus:
+        a = np.asarray(pre.pop(n.name))
+        off = masks[n.name] != (a > 0.0)
+        total += a.size
+        if off.any():
+            flips += int(off.sum())
+            side = float(np.abs(a[off]).max() / np.abs(a).max())
+            worst_side = max(worst_side, side)
+            if side > 1e-4:
+                raise SystemExit(
+                    f"ReLU check FAILED for {n.name}: {int(off.sum())} elements "
+                    f"on the other side of the kink, up to {side:.2e} of max|a|"
+                )
+    print(f"ReLU sides: {flips} of {total} elements differ from the oracle's, "
+          f"all within {worst_side:.2e} of their layer's max|a|")
+    if not np.allclose(got_logits, np.asarray(want_logits), rtol=1e-4, atol=1e-5):
+        raise SystemExit("forward check FAILED: logits differ from the oracle")
+    with jax.default_matmul_precision("highest"):
         want_grads = jax.jit(jax.grad(
             lambda p, x, y, m: _dag_oracle_loss(graph, p, x, y, m)
         ))(params, x, onehot, masks)
-    if not np.allclose(got_logits, want_logits, rtol=1e-4, atol=1e-5):
-        raise SystemExit("forward check FAILED: logits differ from the oracle")
     worst = 0.0
     for p in graph.param_shapes():
         got = np.asarray(got_grads.pop(p))
@@ -743,7 +746,7 @@ def _check_lm_grads(graph, program, batch_fn, vocab, *, cache, fuse,
             raise SystemExit(f"gradient check FAILED for {p}: rel err {rel:.2e}")
     n = len(graph.param_shapes())
     print(f"gradient check vs jax.grad: {n} params OK (worst rel err {worst:.2e})")
-    return {"worst_rel_err": worst, "n_params": n}
+    return {"worst_rel_err": worst, "n_params": n, "relu_flips": flips}
 
 
 def run_ntx_lm(model: str, steps: int, batch: int, seq: int, *,
@@ -846,7 +849,8 @@ def run_ntx_lm(model: str, steps: int, batch: int, seq: int, *,
         res = train_graph(graph, steps, batch_fn, program=program,
                           backend="pallas", interpret=interpret,
                           params=graph.init_params(seed=0),
-                          metrics_path=metrics, cache=cache, fuse=fuse)
+                          metrics_path=metrics, cache=cache, fuse=fuse,
+                          relu_outputs=check_grads)
         if collector is not None:
             if sharded is not None:
                 collector.add_mesh_step(sharded, n_clusters=n_clusters)

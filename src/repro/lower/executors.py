@@ -592,7 +592,8 @@ def _graph_fingerprint(graph):
     )
 
 
-def _step_plan(cache, graph, fusion, design, interpret, *, keep_grads):
+def _step_plan(cache, graph, fusion, design, interpret, *, keep_grads,
+               relu_outputs=False):
     """One jitted callable for the WHOLE fused train step.
 
     The fused walk still dispatches 5-ish plans per step; at millisecond
@@ -611,6 +612,7 @@ def _step_plan(cache, graph, fusion, design, interpret, *, keep_grads):
         _graph_fingerprint(graph),
         segs,
         keep_grads,
+        relu_outputs,
         design,
         bool(interpret),
     )
@@ -620,8 +622,8 @@ def _step_plan(cache, graph, fusion, design, interpret, *, keep_grads):
 
         def raw(j):
             return _graph_step_local(
-                graph, j, plan, graph.batch,
-                keep_grads=keep_grads, fusion=fusion,
+                graph, j, plan, graph.batch, keep_grads=keep_grads,
+                fusion=fusion, relu_outputs=relu_outputs,
             )
 
         return raw
@@ -650,6 +652,7 @@ def run_pallas(
     interpret: bool | None = None,
     cache: PlanCache | None = None,
     fuse: bool = True,
+    relu_outputs: bool = False,
 ):
     """Execute the lowered layer through the cached Pallas plans.
 
@@ -670,6 +673,11 @@ def run_pallas(
     fused kernels — with per-node dispatch as the fallback for steps
     without a fusion rule. ``fuse=False`` is the escape hatch: the original
     one-plan-per-node walk, bit-for-bit the PR-4 behaviour.
+
+    ``relu_outputs`` (train-step programs only) adds the output edge of
+    every ReLU forward the walk runs outside a fused region to the outputs,
+    keyed by edge name — what a gradient check needs to see which side of
+    the kink each element took. It is part of the step's cache key.
     """
     interpret = _resolve_interpret(interpret)
     if cache is None:
@@ -679,11 +687,13 @@ def run_pallas(
     if program.meta.get("pass") == "train_step":
         if "mesh" in program.meta:
             out = _run_pallas_graph_mesh(
-                program, inputs, interpret, cache, fuse=fuse
+                program, inputs, interpret, cache, fuse=fuse,
+                relu_outputs=relu_outputs,
             )
         else:
             out = _run_pallas_graph(
-                program, inputs, interpret, cache, fuse=fuse
+                program, inputs, interpret, cache, fuse=fuse,
+                relu_outputs=relu_outputs,
             )
     else:
         spec = program.meta.get("spec")
@@ -699,7 +709,8 @@ def run_pallas(
     return out
 
 
-def _run_pallas_graph(program, inputs, interpret: bool, cache, fuse=True):
+def _run_pallas_graph(program, inputs, interpret: bool, cache, fuse=True,
+                      relu_outputs=False):
     """Graph-driven Pallas execution of one whole-train-step program.
 
     Walks the :class:`repro.lower.graph.NetworkGraph` behind ``program`` in
@@ -719,15 +730,29 @@ def _run_pallas_graph(program, inputs, interpret: bool, cache, fuse=True):
     _record_fusion(obs.get_active(), fusion)
     if fusion is not None and obs_trace.get_active_trace() is None:
         step = _step_plan(cache, graph, fusion, program.design.name,
-                          interpret, keep_grads=keep_grads)
+                          interpret, keep_grads=keep_grads,
+                          relu_outputs=relu_outputs)
         return step(j)
     plan = _dispatch_plan(cache, program.design.name, interpret)
     return _graph_step_local(graph, j, plan, graph.batch,
-                             keep_grads=keep_grads, fusion=fusion)
+                             keep_grads=keep_grads, fusion=fusion,
+                             relu_outputs=relu_outputs)
+
+
+def _relu_edges(graph, fusion) -> tuple[str, ...]:
+    """Output edges of the ReLU forwards a walk runs outside fused regions
+    (all of them without ``fusion``): the ``relu_outputs`` of a step."""
+    ran = None if fusion is None else set(fusion.fallback_steps)
+    return tuple(
+        n.out_edge for n in graph.nodes
+        if isinstance(n.spec, ReluSpec)
+        and (ran is None or f"{n.name}:fwd" in ran)
+    )
 
 
 def _graph_step_local(graph, j, plan, B, *, keep_grads=True,
-                      grad_reduce=None, batched=None, fusion=None):
+                      grad_reduce=None, batched=None, fusion=None,
+                      relu_outputs=False):
     """One train step over ``B``-image arrays through cached per-node plans.
 
     ``B`` is the batch the arrays actually carry — the graph's full batch
@@ -740,13 +765,17 @@ def _graph_step_local(graph, j, plan, B, *, keep_grads=True,
     the command stream's fwd → loss grad → dW/update/dX schedule exactly;
     with ``fusion`` set it follows the fusion plan's segments instead —
     the same schedule, chains collapsed into region dispatches.
+    ``relu_outputs`` adds the :func:`_relu_edges` activations to the outputs.
     """
     reduce = grad_reduce or (lambda g: g)
     batched = (B > 1) if batched is None else batched
     if fusion is not None:
-        return _walk_fused(graph, j, plan, B, fusion,
-                           keep_grads=keep_grads, reduce=reduce,
-                           batched=batched)
+        outs, env = _walk_fused(graph, j, plan, B, fusion,
+                                keep_grads=keep_grads, reduce=reduce,
+                                batched=batched)
+        if relu_outputs:
+            outs.update((e, env[e]) for e in _relu_edges(graph, fusion))
+        return outs
 
     def bspec(spec):
         return BatchedSpec(spec, B) if batched else spec
@@ -793,6 +822,8 @@ def _graph_step_local(graph, j, plan, B, *, keep_grads=True,
 
     logits = acts[graph.logits_edge]
     outs = {graph.logits_edge: logits}
+    if relu_outputs:
+        outs.update((e, acts[e]) for e in _relu_edges(graph, None))
 
     # loss gradient seeds the per-edge gradient map; DAG fan-out edges
     # accumulate one contribution per consumer (matching the compiled
@@ -874,6 +905,7 @@ def _graph_step_local(graph, j, plan, B, *, keep_grads=True,
 
 def _walk_fused(graph, j, plan, B, fusion, *, keep_grads, reduce, batched):
     """The fused segment walk: region kernels + per-node fallback steps.
+    Returns the step's outputs and the walk's edge environment.
 
     Activations and activation gradients live in ``env`` keyed by edge
     name (gradient of edge ``e`` is ``d_<e>``) so region dispatches and
@@ -1044,11 +1076,11 @@ def _walk_fused(graph, j, plan, B, fusion, *, keep_grads, reduce, batched):
             else:  # <param>_new / v_<param>_new epilogue results
                 outs[name] = v
     outs[graph.logits_edge] = env[graph.logits_edge]
-    return outs
+    return outs, env
 
 
 def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
-                           fuse=True):
+                           fuse=True, relu_outputs=False):
     """Data-parallel execution of a mesh-sharded train-step program.
 
     The batch shards over a ``(pod, data)`` jax device mesh shaped like the
@@ -1113,10 +1145,11 @@ def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
         _record_fusion(obs.get_active(), fusion)
         if fusion is not None and obs_trace.get_active_trace() is None:
             step = _step_plan(cache, graph, fusion, program.design.name,
-                              interpret, keep_grads=keep_grads)
+                              interpret, keep_grads=keep_grads,
+                              relu_outputs=relu_outputs)
             return step(j)
         return _graph_step_local(graph, j, plan, B, keep_grads=keep_grads,
-                                 fusion=fusion)
+                                 fusion=fusion, relu_outputs=relu_outputs)
     # inside shard_map the gradient psum must run between dW and the SGD
     # update, so regions keep the updates as per-node fallback dispatches
     fusion = _fusion_for(program, fuse_updates=False) if fuse else None
@@ -1145,12 +1178,15 @@ def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
             out_specs[f"d_{p}"] = P()
         if graph.momentum:
             out_specs[f"v_{p}_new"] = P()
+    if relu_outputs:
+        # activations keep their batch shards, concatenated in batch order
+        out_specs.update((e, P(dp_axes)) for e in _relu_edges(graph, fusion))
 
     def per_shard(shard_j):
         return _graph_step_local(
             graph, shard_j, plan, B // n_alive, keep_grads=keep_grads,
             grad_reduce=lambda g: jax.lax.psum(g, dp_axes), batched=True,
-            fusion=fusion,
+            fusion=fusion, relu_outputs=relu_outputs,
         )
 
     return compat.shard_map(

@@ -1221,6 +1221,7 @@ def train_graph(
     metrics_path=None,
     fuse: bool = True,
     chaos=None,
+    relu_outputs: bool = False,
 ) -> dict[str, Any]:
     """Train ``graph`` for ``steps`` through one compiled NtxProgram.
 
@@ -1243,6 +1244,9 @@ def train_graph(
     checkpoint — gradients match the healthy run because partial results
     never commit. Replayed steps re-enter ``batch_fn(i)`` at the same
     ``i`` (the (seed, step) data contract makes the stream bit-identical).
+
+    ``relu_outputs`` is passed to :func:`run_pallas`, so a later gradient
+    check on the same program reuses the steps' compiled executable.
     """
     import time as _time
     from contextlib import nullcontext
@@ -1284,7 +1288,7 @@ def train_graph(
                     elif backend == "pallas":
                         outs = executors.run_pallas(
                             program, inputs, interpret=interpret, cache=cache,
-                            fuse=fuse,
+                            fuse=fuse, relu_outputs=relu_outputs,
                         )
                         import jax as _jax
 

@@ -497,23 +497,55 @@ def test_oracle_relu_masks_fix_the_kink_side():
 
 
 def test_lm_grad_check_uses_the_steps_own_relu_masks():
-    from repro.launch.train import _check_lm_grads, _pallas_step_with_relu_masks
-    from repro.lower import PlanCache, ReluSpec, lm_token_batches, run_pallas
+    """The check re-runs the step through run_pallas, whose ``relu_outputs``
+    adds every ReLU's output edge without changing any other output, and
+    reuses the executable the training steps compiled."""
+    from repro.launch.train import _check_lm_grads
+    from repro.lower import PlanCache, ReluSpec, lm_token_batches, run_pallas, train_graph
+    from repro.lower.executors import _cache_stats
 
     graph = _tiny_lm()
     prog = lower_training_step(graph)
     V = graph.loss.classes
     batch_fn = lm_token_batches(np.random.RandomState(0), graph.batch, 6, V)
     cache = PlanCache()
+    train_graph(graph, 1, batch_fn, program=prog, interpret=True, cache=cache,
+                params=graph.init_params(seed=0), relu_outputs=True)
+    traces = _cache_stats(cache)[2]
     res = _check_lm_grads(graph, prog, batch_fn, V, cache=cache, fuse=True, interpret=True)
     assert res["n_params"] == len(graph.param_shapes()) and res["worst_rel_err"] < 1e-4
+    assert _cache_stats(cache)[2] == traces  # no new step was traced
 
     x, labels = batch_fn(1)
     inputs = {"x": x, "onehot": np.eye(V, dtype=np.float32)[labels], **graph.init_params()}
-    outs, masks = _pallas_step_with_relu_masks(
-        prog, inputs, cache=cache, fuse=True, interpret=True
-    )
-    assert sorted(masks) == sorted(n.name for n in graph.nodes if isinstance(n.spec, ReluSpec))
+    outs = run_pallas(prog, inputs, cache=cache, interpret=True, relu_outputs=True)
     want = run_pallas(prog, inputs, cache=cache, interpret=True)
+    relu_edges = {n.out_edge for n in graph.nodes if isinstance(n.spec, ReluSpec)}
+    assert set(outs) == set(want) | relu_edges and not relu_edges & set(want)
     for k in want:
         np.testing.assert_allclose(np.asarray(outs[k]), np.asarray(want[k]), rtol=1e-5, atol=1e-6)
+    for e in relu_edges:
+        assert float(np.asarray(outs[e]).min()) == 0.0
+
+
+def test_lm_grad_check_fails_a_planted_wrong_relu_mask(monkeypatch):
+    """A step whose ReLUs zero everything under 0.1 std of their input puts
+    elements well away from zero on the wrong side of the kink: the check
+    must refuse it, whatever the logits and gradients would say."""
+    from repro.launch.train import _check_lm_grads
+    from repro.lower import PlanCache, ReluSpec, executors, lm_token_batches
+
+    real = executors._plan_callable
+
+    def planted(spec, pass_, interpret):
+        if isinstance(spec, ReluSpec) and pass_ == "fwd":
+            return lambda j: {"y": jnp.where(j["x"] > 0.1 * jnp.std(j["x"]), j["x"], 0.0)}
+        return real(spec, pass_, interpret)
+
+    monkeypatch.setattr(executors, "_plan_callable", planted)
+    graph = _tiny_lm()
+    prog = lower_training_step(graph)
+    V = graph.loss.classes
+    batch_fn = lm_token_batches(np.random.RandomState(0), graph.batch, 6, V)
+    with pytest.raises(SystemExit, match="ReLU check FAILED"):
+        _check_lm_grads(graph, prog, batch_fn, V, cache=PlanCache(), fuse=True, interpret=True)
