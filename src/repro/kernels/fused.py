@@ -28,6 +28,8 @@ the input gradient is the transposed conv — dilate dy by the stride, pad by
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -336,6 +338,14 @@ def _stage_updates(region, totals, env):
     return outs
 
 
+def kernel_name(region) -> str:
+    """The region kernel's name, which the compiled step's instruction
+    carries (``<name>.<n>``): its first and last stage, as in
+    ``RegionSpec.label``, e.g. ``fused_c1_fwd__c1_dw``."""
+    first, last = region.stages[0], region.stages[-1]
+    return re.sub(r"\W", "_", f"fused_{first.node}_{first.pass_}__{last.node}_{last.pass_}")
+
+
 def build_region_callable(region, *, interpret: bool):
     """Compile one RegionSpec into a dict -> dict jax callable.
 
@@ -451,6 +461,7 @@ def build_region_callable(region, *, interpret: bool):
                 vmem_limit_bytes=VMEM_LIMIT_BYTES,
             ),
             interpret=interpret,
+            name=kernel_name(region),
         )(*[j[n] for n in streamed], *[j[n] for n in resident])
         if not isinstance(res, (list, tuple)):
             res = (res,)

@@ -157,6 +157,7 @@ def streaming_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         interpret=interpret,
+        name="streaming_matmul",
     )(ap, bp)
     return out[:m, :n]
 

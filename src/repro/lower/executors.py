@@ -510,26 +510,26 @@ def _record_cache_delta(reg, cache: PlanCache, before) -> None:
         reg.inc("calls", c - c0)
 
 
-def _dispatch_plan(cache: PlanCache, design: str, interpret: bool):
-    """The graph walkers' (spec, pass) -> plan closure, trace-span aware.
+def _dispatch_plan(cache: PlanCache, design: str, interpret: bool, col=None):
+    """The graph walkers' (spec, pass) -> plan closure.
 
-    With a :class:`repro.obs.trace.TraceCollector` active, every plan
+    With ``col`` (a :class:`repro.obs.trace.TraceCollector`), every plan
     invocation is wrapped in a host-side dispatch span (the wall time jax
     spends entering the jitted executable — Pallas dispatch overhead).
+    Pass it only where plans are dispatched one by one from the host:
+    inside a jitted step or a ``shard_map`` body a plan call is traced,
+    not run, and its span would time the tracing.
     """
-    col = obs_trace.get_active_trace()
 
     def plan(spec, pass_):
         p = cache.get(spec, pass_, design, interpret)
         if col is None:
             return p
 
-        label = getattr(spec, "label", None)  # RegionSpec names its chain
-        name = label or f"{type(spec).__name__}:{pass_}"
-        cat = "fused" if label else "dispatch"
+        name = f"{type(spec).__name__}:{pass_}"
 
         def timed(j):
-            with col.host_span(name, tid="dispatch", cat=cat):
+            with col.host_span(name, tid="dispatch", cat="dispatch"):
                 return p(j)
 
         return timed
@@ -601,8 +601,9 @@ def _step_plan(cache, graph, fusion, design, interpret, *, keep_grads,
     themselves. Caching the entire segment walk as a single plan — keyed by
     the graph fingerprint plus the fusion plan's segment tuple (RegionSpecs
     are frozen) — collapses a step to one dispatch, with the region
-    pallas_calls inlined into the step executable at trace time. Only used
-    when no TraceCollector is active: traces want the per-plan host spans.
+    pallas_calls inlined into the step executable at trace time. A
+    TraceCollector sees the step as one dispatch (``train_graph``'s
+    ``dispatch`` span); the executable is the same with or without one.
     """
     segs = tuple(
         s.step if s.region is None else s.region for s in fusion.segments
@@ -698,7 +699,8 @@ def run_pallas(
     else:
         spec = program.meta.get("spec")
         pass_ = program.meta.get("pass", "fwd")
-        plan = _dispatch_plan(cache, program.design.name, interpret)(spec, pass_)
+        plan = _dispatch_plan(cache, program.design.name, interpret,
+                              obs_trace.get_active_trace())(spec, pass_)
         out = plan(_as_jax_f32(inputs))
     if reg is not None:
         # The counters are the *program's* closed-form offload/DMA
@@ -728,15 +730,15 @@ def _run_pallas_graph(program, inputs, interpret: bool, cache, fuse=True,
     j = _as_jax_f32(inputs)
     fusion = _fusion_for(program, fuse_updates=True) if fuse else None
     _record_fusion(obs.get_active(), fusion)
-    if fusion is not None and obs_trace.get_active_trace() is None:
+    if fusion is not None:
         step = _step_plan(cache, graph, fusion, program.design.name,
                           interpret, keep_grads=keep_grads,
                           relu_outputs=relu_outputs)
         return step(j)
-    plan = _dispatch_plan(cache, program.design.name, interpret)
+    plan = _dispatch_plan(cache, program.design.name, interpret,
+                          obs_trace.get_active_trace())
     return _graph_step_local(graph, j, plan, graph.batch,
-                             keep_grads=keep_grads, fusion=fusion,
-                             relu_outputs=relu_outputs)
+                             keep_grads=keep_grads, relu_outputs=relu_outputs)
 
 
 def _relu_edges(graph, fusion) -> tuple[str, ...]:
@@ -766,7 +768,11 @@ def _graph_step_local(graph, j, plan, B, *, keep_grads=True,
     with ``fusion`` set it follows the fusion plan's segments instead —
     the same schedule, chains collapsed into region dispatches.
     ``relu_outputs`` adds the :func:`_relu_edges` activations to the outputs.
+    Each step of the walk runs under ``jax.named_scope("<node>:<pass>")``,
+    so its operations carry that name in the compiled step.
     """
+    import jax
+
     reduce = grad_reduce or (lambda g: g)
     batched = (B > 1) if batched is None else batched
     if fusion is not None:
@@ -783,42 +789,43 @@ def _graph_step_local(graph, j, plan, B, *, keep_grads=True,
     # forward
     acts = {graph.input_edge: j[graph.input_edge]}
     for node in graph.nodes:
-        s, a = node.spec, acts[node.in_edge]
-        if isinstance(s, Conv2dSpec):
-            y = plan(bspec(s), "fwd")({"x": a, "w": j[node.param]})["y"]
-        elif isinstance(s, MatmulSpec):
-            y = plan(s, "fwd")({"a": a, "b": j[node.param]})["c"]
-        elif isinstance(s, BiasSpec):
-            y = plan(s, "fwd")({"x": a.reshape(-1, s.c), "b": j[node.param]})
-            y = y["y"].reshape(a.shape)
-        elif isinstance(s, ReluSpec):
-            whole = ReluSpec((B,) + tuple(s.shape)) if batched else s
-            y = plan(whole, "fwd")({"x": a})["y"]
-        elif isinstance(s, MaxPool2dSpec):
-            y = plan(bspec(s), "fwd")({"x": a})["y"]
-        elif isinstance(s, FlattenSpec):
-            y = a.reshape((B, s.size) if batched else (s.size,))
-        elif isinstance(s, AttentionSpec):
-            # per-sequence node over token-row activations (rows = B*S)
-            xb = a.reshape(-1, s.seq, 3 * s.d)
-            y = plan(BatchedSpec(s, xb.shape[0]), "fwd")({"x": xb})["y"]
-            y = y.reshape(-1, s.d)
-        elif isinstance(s, LayerNormSpec):
-            y = plan(s, "fwd")({"x": a, "w": j[node.param]})["y"]
-        elif isinstance(s, ResidualAddSpec):
-            y = plan(s, "fwd")(
-                {"x": a, "x2": acts[node.aux_edges[0]]}
-            )["y"]
-        elif isinstance(s, EmbeddingSpec):
-            y = plan(s, "fwd")({"x": a, "w": j[node.param]})["y"]
-        elif isinstance(s, PosEmbedSpec):
-            # -1, not s.batch: mesh shards walk with a local batch
-            xb = a.reshape(-1, s.seq, s.d)
-            y = plan(s, "fwd")({"x": xb, "w": j[node.param]})["y"]
-            y = y.reshape(-1, s.d)
-        else:
-            raise TypeError(f"no graph route for {type(s).__name__}")
-        acts[node.out_edge] = y
+        with jax.named_scope(f"{node.name}:fwd"):
+            s, a = node.spec, acts[node.in_edge]
+            if isinstance(s, Conv2dSpec):
+                y = plan(bspec(s), "fwd")({"x": a, "w": j[node.param]})["y"]
+            elif isinstance(s, MatmulSpec):
+                y = plan(s, "fwd")({"a": a, "b": j[node.param]})["c"]
+            elif isinstance(s, BiasSpec):
+                y = plan(s, "fwd")({"x": a.reshape(-1, s.c), "b": j[node.param]})
+                y = y["y"].reshape(a.shape)
+            elif isinstance(s, ReluSpec):
+                whole = ReluSpec((B,) + tuple(s.shape)) if batched else s
+                y = plan(whole, "fwd")({"x": a})["y"]
+            elif isinstance(s, MaxPool2dSpec):
+                y = plan(bspec(s), "fwd")({"x": a})["y"]
+            elif isinstance(s, FlattenSpec):
+                y = a.reshape((B, s.size) if batched else (s.size,))
+            elif isinstance(s, AttentionSpec):
+                # per-sequence node over token-row activations (rows = B*S)
+                xb = a.reshape(-1, s.seq, 3 * s.d)
+                y = plan(BatchedSpec(s, xb.shape[0]), "fwd")({"x": xb})["y"]
+                y = y.reshape(-1, s.d)
+            elif isinstance(s, LayerNormSpec):
+                y = plan(s, "fwd")({"x": a, "w": j[node.param]})["y"]
+            elif isinstance(s, ResidualAddSpec):
+                y = plan(s, "fwd")(
+                    {"x": a, "x2": acts[node.aux_edges[0]]}
+                )["y"]
+            elif isinstance(s, EmbeddingSpec):
+                y = plan(s, "fwd")({"x": a, "w": j[node.param]})["y"]
+            elif isinstance(s, PosEmbedSpec):
+                # -1, not s.batch: mesh shards walk with a local batch
+                xb = a.reshape(-1, s.seq, s.d)
+                y = plan(s, "fwd")({"x": xb, "w": j[node.param]})["y"]
+                y = y.reshape(-1, s.d)
+            else:
+                raise TypeError(f"no graph route for {type(s).__name__}")
+            acts[node.out_edge] = y
 
     logits = acts[graph.logits_edge]
     outs = {graph.logits_edge: logits}
@@ -828,9 +835,10 @@ def _graph_step_local(graph, j, plan, B, *, keep_grads=True,
     # loss gradient seeds the per-edge gradient map; DAG fan-out edges
     # accumulate one contribution per consumer (matching the compiled
     # program's partial + accumulate-step schedule)
-    grads = {graph.logits_edge: plan(graph.loss, "dx")(
-        {"z": logits, "onehot": j[graph.label_edge]}
-    )["dz"]}
+    with jax.named_scope("loss:dx"):
+        grads = {graph.logits_edge: plan(graph.loss, "dx")(
+            {"z": logits, "onehot": j[graph.label_edge]}
+        )["dz"]}
 
     def add_grad(edge, v):
         grads[edge] = grads[edge] + v if edge in grads else v
@@ -840,66 +848,69 @@ def _graph_step_local(graph, j, plan, B, *, keep_grads=True,
         s, a_in = node.spec, acts[node.in_edge]
         g = grads[node.out_edge]
         if node.param is not None:
-            p = node.param
-            if isinstance(s, Conv2dSpec):
-                dw = plan(bspec(s), "dw")({"x": a_in, "dy": g})["dw"]
-            elif isinstance(s, MatmulSpec):
-                dw = plan(s, "dw")({"a": a_in, "dy": g})["dw"]
-            elif isinstance(s, BiasSpec):
-                dw = plan(s, "dw")({"dy": g.reshape(-1, s.c)})["db"]
-            elif isinstance(s, (LayerNormSpec, EmbeddingSpec)):
-                dw = plan(s, "dw")({"x": a_in, "dy": g})["dw"]
-            elif isinstance(s, PosEmbedSpec):
-                dw = plan(s, "dw")(
-                    {"dy": g.reshape(-1, s.seq, s.d)}
-                )["dw"]
-            else:
-                raise TypeError(f"no dW route for {type(s).__name__}")
-            dw = reduce(dw)
-            if keep_grads:
-                outs[f"d_{p}"] = dw
-            u_spec = SgdUpdateSpec(
-                n=dw.size, lr=graph.lr, momentum=graph.momentum
-            )
-            u_in = {"w": j[p].reshape(-1), "dw": dw.reshape(-1)}
-            if graph.momentum:
-                u_in["v"] = j[f"v_{p}"].reshape(-1)
-            u = plan(u_spec, "upd")(u_in)
-            outs[f"{p}_new"] = u["w_new"].reshape(j[p].shape)
-            if graph.momentum:
-                outs[f"v_{p}_new"] = u["v_new"].reshape(j[p].shape)
+            with jax.named_scope(f"{node.name}:dw"):
+                p = node.param
+                if isinstance(s, Conv2dSpec):
+                    dw = plan(bspec(s), "dw")({"x": a_in, "dy": g})["dw"]
+                elif isinstance(s, MatmulSpec):
+                    dw = plan(s, "dw")({"a": a_in, "dy": g})["dw"]
+                elif isinstance(s, BiasSpec):
+                    dw = plan(s, "dw")({"dy": g.reshape(-1, s.c)})["db"]
+                elif isinstance(s, (LayerNormSpec, EmbeddingSpec)):
+                    dw = plan(s, "dw")({"x": a_in, "dy": g})["dw"]
+                elif isinstance(s, PosEmbedSpec):
+                    dw = plan(s, "dw")(
+                        {"dy": g.reshape(-1, s.seq, s.d)}
+                    )["dw"]
+                else:
+                    raise TypeError(f"no dW route for {type(s).__name__}")
+                dw = reduce(dw)
+                if keep_grads:
+                    outs[f"d_{p}"] = dw
+            with jax.named_scope(f"{node.name}:upd"):
+                u_spec = SgdUpdateSpec(
+                    n=dw.size, lr=graph.lr, momentum=graph.momentum
+                )
+                u_in = {"w": j[p].reshape(-1), "dw": dw.reshape(-1)}
+                if graph.momentum:
+                    u_in["v"] = j[f"v_{p}"].reshape(-1)
+                u = plan(u_spec, "upd")(u_in)
+                outs[f"{p}_new"] = u["w_new"].reshape(j[p].shape)
+                if graph.momentum:
+                    outs[f"v_{p}_new"] = u["v_new"].reshape(j[p].shape)
         if node.in_edge == graph.input_edge:
             continue
-        if isinstance(s, Conv2dSpec):
-            gx = plan(bspec(s), "dx")({"dy": g, "w": j[node.param]})["dx"]
-        elif isinstance(s, MatmulSpec):
-            gx = plan(s, "dx")({"dy": g, "b": j[node.param]})["dx"]
-        elif isinstance(s, ReluSpec):
-            whole = ReluSpec((B,) + tuple(s.shape)) if batched else s
-            gx = plan(whole, "dx")({"x": a_in, "dy": g})["dx"]
-        elif isinstance(s, MaxPool2dSpec):
-            gx = plan(bspec(s), "dx")({"x": a_in, "dy": g})["dx"]
-        elif isinstance(s, AttentionSpec):
-            xb = a_in.reshape(-1, s.seq, 3 * s.d)
-            gx = plan(BatchedSpec(s, xb.shape[0]), "dx")(
-                {"x": xb, "dy": g.reshape(-1, s.seq, s.d)}
-            )["dx"].reshape(a_in.shape)
-        elif isinstance(s, LayerNormSpec):
-            gx = plan(s, "dx")(
-                {"x": a_in, "w": j[node.param], "dy": g}
-            )["dx"]
-        elif isinstance(s, ResidualAddSpec):
-            gx = plan(s, "dx")({"dy": g})["dx"]
-            add_grad(node.aux_edges[0], gx)
-        elif isinstance(s, PosEmbedSpec):
-            gx = plan(s, "dx")(
-                {"dy": g.reshape(-1, s.seq, s.d)}
-            )["dx"].reshape(-1, s.d)
-        elif isinstance(s, (FlattenSpec, BiasSpec)):
-            gx = g.reshape(a_in.shape)
-        else:
-            raise TypeError(f"no dX route for {type(s).__name__}")
-        add_grad(node.in_edge, gx)
+        with jax.named_scope(f"{node.name}:dx"):
+            if isinstance(s, Conv2dSpec):
+                gx = plan(bspec(s), "dx")({"dy": g, "w": j[node.param]})["dx"]
+            elif isinstance(s, MatmulSpec):
+                gx = plan(s, "dx")({"dy": g, "b": j[node.param]})["dx"]
+            elif isinstance(s, ReluSpec):
+                whole = ReluSpec((B,) + tuple(s.shape)) if batched else s
+                gx = plan(whole, "dx")({"x": a_in, "dy": g})["dx"]
+            elif isinstance(s, MaxPool2dSpec):
+                gx = plan(bspec(s), "dx")({"x": a_in, "dy": g})["dx"]
+            elif isinstance(s, AttentionSpec):
+                xb = a_in.reshape(-1, s.seq, 3 * s.d)
+                gx = plan(BatchedSpec(s, xb.shape[0]), "dx")(
+                    {"x": xb, "dy": g.reshape(-1, s.seq, s.d)}
+                )["dx"].reshape(a_in.shape)
+            elif isinstance(s, LayerNormSpec):
+                gx = plan(s, "dx")(
+                    {"x": a_in, "w": j[node.param], "dy": g}
+                )["dx"]
+            elif isinstance(s, ResidualAddSpec):
+                gx = plan(s, "dx")({"dy": g})["dx"]
+                add_grad(node.aux_edges[0], gx)
+            elif isinstance(s, PosEmbedSpec):
+                gx = plan(s, "dx")(
+                    {"dy": g.reshape(-1, s.seq, s.d)}
+                )["dx"].reshape(-1, s.d)
+            elif isinstance(s, (FlattenSpec, BiasSpec)):
+                gx = g.reshape(a_in.shape)
+            else:
+                raise TypeError(f"no dX route for {type(s).__name__}")
+            add_grad(node.in_edge, gx)
     return outs
 
 
@@ -914,6 +925,8 @@ def _walk_fused(graph, j, plan, B, fusion, *, keep_grads, reduce, batched):
     identity — the fuser only emits them on the single-device path.
     """
     import dataclasses
+
+    import jax
 
     nodes = {n.name: n for n in graph.nodes}
     env = {graph.input_edge: j[graph.input_edge]}
@@ -1054,7 +1067,8 @@ def _walk_fused(graph, j, plan, B, fusion, *, keep_grads, reduce, batched):
 
     for seg in fusion.segments:
         if seg.region is None:
-            exec_step(seg.step)
+            with jax.named_scope(seg.step):
+                exec_step(seg.step)
             continue
         region = seg.region
         if region.batch != B:
@@ -1063,7 +1077,8 @@ def _walk_fused(graph, j, plan, B, fusion, *, keep_grads, reduce, batched):
         for name, is_b in region.inputs:
             v = env[name] if name in env else j[name]
             ins[name] = v[None] if (is_b and not batched) else v
-        ro = plan(region, "region")(ins)
+        with jax.named_scope(region.label):
+            ro = plan(region, "region")(ins)
         for name, kind in region.outputs:
             v = ro[name]
             if kind == "batched":
@@ -1132,7 +1147,6 @@ def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
     B = graph.batch
     keep_grads = program.meta.get("keep_grads", True)
     j = _as_jax_f32(inputs)
-    plan = _dispatch_plan(cache, program.design.name, interpret)
 
     if jax.device_count() < n_alive and not interpret:
         raise RuntimeError(
@@ -1143,13 +1157,15 @@ def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
     if jax.device_count() < n_alive or B % n_alive:
         fusion = _fusion_for(program, fuse_updates=True) if fuse else None
         _record_fusion(obs.get_active(), fusion)
-        if fusion is not None and obs_trace.get_active_trace() is None:
+        if fusion is not None:
             step = _step_plan(cache, graph, fusion, program.design.name,
                               interpret, keep_grads=keep_grads,
                               relu_outputs=relu_outputs)
             return step(j)
+        plan = _dispatch_plan(cache, program.design.name, interpret,
+                              obs_trace.get_active_trace())
         return _graph_step_local(graph, j, plan, B, keep_grads=keep_grads,
-                                 fusion=fusion, relu_outputs=relu_outputs)
+                                 relu_outputs=relu_outputs)
     # inside shard_map the gradient psum must run between dW and the SGD
     # update, so regions keep the updates as per-node fallback dispatches
     fusion = _fusion_for(program, fuse_updates=False) if fuse else None
@@ -1181,6 +1197,8 @@ def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
     if relu_outputs:
         # activations keep their batch shards, concatenated in batch order
         out_specs.update((e, P(dp_axes)) for e in _relu_edges(graph, fusion))
+
+    plan = _dispatch_plan(cache, program.design.name, interpret)
 
     def per_shard(shard_j):
         return _graph_step_local(

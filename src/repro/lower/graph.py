@@ -32,6 +32,7 @@ addresses.
 from __future__ import annotations
 
 import math
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable
 
@@ -1205,6 +1206,11 @@ def softmax_xent_loss(logits: np.ndarray, labels: np.ndarray) -> float:
     return float(np.mean(lse - z[np.arange(len(labels)), labels]))
 
 
+#: what a span site of :func:`train_graph` enters when no collector is
+#: installed
+_NO_SPAN = nullcontext()
+
+
 def train_graph(
     graph: NetworkGraph,
     steps: int,
@@ -1247,9 +1253,19 @@ def train_graph(
 
     ``relu_outputs`` is passed to :func:`run_pallas`, so a later gradient
     check on the same program reuses the steps' compiled executable.
+
+    With a :class:`repro.obs.trace.TraceCollector` installed, each step
+    records a ``step`` span (args ``step``; counters ``h2d_bytes``, the
+    host arrays handed to the executor, ``d2h_bytes``, the logits read
+    back, and ``plan_retraces``, the plan cache's traces during the step)
+    over the leaf spans ``batch`` (``batch_fn``), ``labels`` (the one-hot
+    rows), ``dispatch`` (the executor call until it returns), ``wait``
+    (until the device is done), ``readback`` (logits to numpy), ``loss``
+    (the float64 host loss) and ``commit`` (the ``*_new`` params, the
+    metrics writer, the chaos hook). The step that runs is the same with
+    or without a collector.
     """
     import time as _time
-    from contextlib import nullcontext
 
     from repro.lower import executors
     from repro.obs import report as obs_report
@@ -1268,6 +1284,17 @@ def train_graph(
         if registry is not None
         else nullcontext()
     )
+    col = obs_trace.get_active_trace()
+    plan_cache = cache if cache is not None else executors.PLAN_CACHE
+
+    def span(name, **kw):
+        return _NO_SPAN if col is None else col.host_span(
+            name, tid="train_graph", cat="step", **kw)
+
+    if backend == "pallas":
+        import jax
+    elif backend != "reference":
+        raise ValueError(f"unknown backend {backend!r}")
     try:
         with install:
             if chaos is not None:
@@ -1275,61 +1302,75 @@ def train_graph(
             i = 0
             while i < steps:
                 t0 = _time.perf_counter()
-                x, labels = batch_fn(i)
-                inputs = {graph.input_edge: np.asarray(x, np.float32),
-                          graph.label_edge: one_hot_rows(labels, graph.loss.classes),
-                          **params}
-                step_scope = (
-                    reg.scope(f"step{i}") if reg is not None else nullcontext()
-                )
-                with step_scope:
-                    if backend == "reference":
-                        outs = executors.run_reference(program, inputs)
-                    elif backend == "pallas":
-                        outs = executors.run_pallas(
-                            program, inputs, interpret=interpret, cache=cache,
-                            fuse=fuse, relu_outputs=relu_outputs,
-                        )
-                        import jax as _jax
-
-                        # jax dispatch is async: wait for the step's device
-                        # work so the recorded wall is the true step time
-                        _jax.block_until_ready(outs)
-                    else:
-                        raise ValueError(f"unknown backend {backend!r}")
-                if chaos is not None:
-                    action = chaos.intercept(i, outs, params)
-                    if action is not None:
-                        # the step is discarded before commit: swap in the
-                        # re-sharded program / rewound params and replay
-                        if action.program is not None:
-                            program = action.program
-                        if action.params is not None:
-                            params = dict(action.params)
-                        del losses[action.resume_step:]
-                        del walls[action.resume_step:]
-                        i = action.resume_step
-                        continue
-                losses.append(
-                    softmax_xent_loss(np.asarray(outs[graph.logits_edge]), labels)
-                )
-                # keep updated params as whatever the backend returned (jax
-                # arrays stay on device between pallas steps — no per-step
-                # host round trip); materialized to numpy once after the loop
-                for p in graph.param_shapes():
-                    params[p] = outs[f"{p}_new"]
-                    if graph.momentum:
-                        params[f"v_{p}"] = outs[f"v_{p}_new"]
-                walls.append(_time.perf_counter() - t0)
-                if writer is not None:
-                    writer.write({
-                        "step": i,
-                        "loss": losses[-1],
-                        "wall_s": walls[-1],
-                        "counters": reg.totals(f"step{i}/") if reg is not None else {},
-                    })
-                if chaos is not None:
-                    chaos.committed(i, params)
+                with span("step", step=i, args={"step": i}) as counters:
+                    if col is not None:
+                        traces0 = executors._cache_stats(plan_cache)[2]
+                    with span("batch"):
+                        x, labels = batch_fn(i)
+                        x = np.asarray(x, np.float32)
+                    with span("labels"):
+                        onehot = one_hot_rows(labels, graph.loss.classes)
+                    inputs = {graph.input_edge: x, graph.label_edge: onehot, **params}
+                    step_scope = (
+                        reg.scope(f"step{i}") if reg is not None else nullcontext()
+                    )
+                    with step_scope, span("dispatch"):
+                        if backend == "reference":
+                            outs = executors.run_reference(program, inputs)
+                        else:
+                            outs = executors.run_pallas(
+                                program, inputs, interpret=interpret, cache=cache,
+                                fuse=fuse, relu_outputs=relu_outputs,
+                            )
+                    with span("wait"):
+                        if backend == "pallas":
+                            # jax dispatch is async: wait for the step's device
+                            # work so the recorded wall is the true step time
+                            jax.block_until_ready(outs)
+                    if chaos is not None:
+                        action = chaos.intercept(i, outs, params)
+                        if action is not None:
+                            # the step is discarded before commit: swap in the
+                            # re-sharded program / rewound params and replay
+                            if action.program is not None:
+                                program = action.program
+                            if action.params is not None:
+                                params = dict(action.params)
+                            del losses[action.resume_step:]
+                            del walls[action.resume_step:]
+                            i = action.resume_step
+                            continue
+                    with span("readback"):
+                        logits = np.asarray(outs[graph.logits_edge])
+                    with span("loss"):
+                        losses.append(softmax_xent_loss(logits, labels))
+                    with span("commit"):
+                        # keep updated params as whatever the backend returned
+                        # (jax arrays stay on device between pallas steps — no
+                        # per-step host round trip); materialized to numpy
+                        # once after the loop
+                        for p in graph.param_shapes():
+                            params[p] = outs[f"{p}_new"]
+                            if graph.momentum:
+                                params[f"v_{p}"] = outs[f"v_{p}_new"]
+                        walls.append(_time.perf_counter() - t0)
+                        if writer is not None:
+                            writer.write({
+                                "step": i,
+                                "loss": losses[-1],
+                                "wall_s": walls[-1],
+                                "counters": (reg.totals(f"step{i}/")
+                                             if reg is not None else {}),
+                            })
+                        if chaos is not None:
+                            chaos.committed(i, params)
+                    if col is not None:
+                        counters["h2d_bytes"] = sum(
+                            v.nbytes for v in inputs.values()
+                            if isinstance(v, np.ndarray))
+                        counters["d2h_bytes"] = logits.nbytes
+                        counters["plan_retraces"] = (
+                            executors._cache_stats(plan_cache)[2] - traces0)
                 i += 1
     finally:
         if writer is not None:

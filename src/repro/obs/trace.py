@@ -12,16 +12,22 @@ chrome-trace JSON that Perfetto (https://ui.perfetto.dev) loads directly:
   * **mesh lanes** (``pid mesh``) — one track per directed link, spans from
     the :class:`repro.runtime.mesh.LinkSchedule` (the systolic update's
     reduce/broadcast passes, ring steps, ...).
-  * **host lanes** (``pid host``) — wall-clock spans for graph lowering
-    (``lower:{node}:{pass}``) and Pallas plan dispatch, recorded live via
-    :meth:`TraceCollector.host_span`.
+  * **host lanes** (``pid host``) — wall-clock spans recorded live via
+    :meth:`TraceCollector.host_span`: graph lowering (``lower:{node}:{pass}``),
+    the per-step phases of :func:`repro.lower.graph.train_graph` (a
+    ``step`` span over ``batch``, ``labels``, ``dispatch``, ``wait``,
+    ``readback``, ``loss`` and ``commit``) and, on the per-node route,
+    Pallas plan dispatch.
   * **flow events** (``ph s/t/f``) — arrows tying a command block's host
     lowering span to its shard execution span and on to the link transfer
     that carries its result across the mesh.
 
-Simulated lanes are in microseconds of modeled time (cycles / f_ntx); host
-lanes are microseconds of wall time rebased to zero. The groups share the
-trace, not a clock — Perfetto renders them as separate process tracks.
+Simulated lanes are in microseconds of modeled time (cycles / f_ntx).
+Host spans are kept as absolute nanoseconds of :func:`clock_ns`, the wall
+clock from which the JAX profiler counts its events (an ``.xplane.pb``'s
+``profile_start_time``), so a span can be laid beside a gap in a profiler
+trace (:meth:`TraceCollector.host_spans`); the chrome export rebases the
+host lanes to zero at the first span, for Perfetto.
 
 Activation mirrors :mod:`repro.obs.counters`: instrument sites check
 :func:`get_active_trace` (one global read) and do nothing when no collector
@@ -33,6 +39,10 @@ from __future__ import annotations
 import json
 import time
 from contextlib import contextmanager
+from typing import NamedTuple
+
+#: The host spans' clock: wall-clock nanoseconds, the profiler's clock.
+clock_ns = time.time_ns
 
 #: Process-wide active collector (None = trace capture off).
 _ACTIVE: "TraceCollector | None" = None
@@ -96,38 +106,68 @@ def block_spans(program, result, n_clusters: int):
             yield (c, b.tag, exec_t0, exec_t1, dma_t0, dma_t1, len(take))
 
 
+class Span(NamedTuple):
+    """One host span: :func:`clock_ns` nanoseconds, the step it belongs to
+    (None outside a step), its args, its id (spans are numbered in the
+    order they open) and the id of the span it opened inside (or None)."""
+
+    name: str
+    t0_ns: int
+    t1_ns: int
+    step: int | None
+    args: dict
+    id: int
+    parent: int | None
+
+
 class TraceCollector:
     """Accumulates chrome-trace events from every layer of the stack."""
 
     def __init__(self, f_ntx: float = 1.5e9):
         self.f_ntx = f_ntx
         self.events: list[dict] = []
-        self._host_origin: float | None = None
+        self.spans: list[Span] = []  # host spans, in the order they close
+        self._open: list[tuple[int, int | None]] = []  # (id, step) stack
+        self._host_origin_ns: int | None = None
         self._flow_id = 0
 
     # -- host (wall-clock) spans --------------------------------------------
 
-    def _now_us(self) -> float:
-        t = time.perf_counter()
-        if self._host_origin is None:
-            self._host_origin = t
-        return (t - self._host_origin) * 1e6
-
     @contextmanager
     def host_span(self, name: str, *, tid: str = "dispatch",
-                  cat: str = "host", args: dict | None = None):
-        """Record a wall-clock span on the ``host`` process track."""
-        t0 = self._now_us()
+                  cat: str = "host", args: dict | None = None,
+                  step: int | None = None):
+        """Record a wall-clock span on the ``host`` process track.
+
+        Yields the span's args dict, which the block may add counters to.
+        ``step`` defaults to the enclosing span's step."""
+        sid = len(self.spans) + len(self._open)
+        parent, parent_step = self._open[-1] if self._open else (None, None)
+        step = parent_step if step is None else step
+        args = dict(args or {})
+        self._open.append((sid, step))
+        t0 = clock_ns()
+        if self._host_origin_ns is None:
+            self._host_origin_ns = t0
         try:
-            yield
+            yield args
         finally:
-            t1 = self._now_us()
+            t1 = clock_ns()
+            self._open.pop()
+            self.spans.append(Span(name, t0, t1, step, args, sid, parent))
             self.events.append({
                 "name": name, "cat": cat, "ph": "X",
                 "pid": "host", "tid": tid,
-                "ts": t0, "dur": max(t1 - t0, 0.01),
-                "args": dict(args or {}),
+                "ts": (t0 - self._host_origin_ns) / 1e3,
+                "dur": max((t1 - t0) / 1e3, 0.01),
+                "args": args,
             })
+
+    def host_spans(self) -> list[tuple[str, int, int, int | None, dict]]:
+        """The host spans as ``(name, t0_ns, t1_ns, step, args)`` on
+        :func:`clock_ns`, in the order they opened."""
+        return [(s.name, s.t0_ns, s.t1_ns, s.step, s.args)
+                for s in sorted(self.spans, key=lambda s: s.id)]
 
     # -- simulated lanes ----------------------------------------------------
 

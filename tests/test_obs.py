@@ -259,17 +259,165 @@ def test_merged_trace_has_all_lanes_and_flows(tmp_path):
 
 def test_dispatch_spans_recorded_by_pallas_executor():
     graph, prog, inputs = _graph_and_inputs()
-    # fused default: the whole step (loss gradient included) is one region,
-    # so the walk records region spans instead of per-node dispatch spans
+    # fused default: the collector does not change the step that runs, so
+    # it is one call of the cached step plan and no span is recorded from
+    # inside its trace
+    cache = PlanCache()
     col = obs.TraceCollector()
     with obs.use_collector(col):
-        run_pallas(prog, inputs, cache=PlanCache())
-    assert "fused" in {e.get("cat") for e in col.events}
-    # the per-node escape hatch still emits one dispatch span per step
+        run_pallas(prog, inputs, cache=cache)
+    steps = [p for p in cache._plans.values() if p.key[0] == "train_step"]
+    assert [p.calls for p in steps] == [1]
+    assert col.spans == [] and col.events == []
+    # the per-node escape hatch dispatches plan by plan from the host, and
+    # records one dispatch span per plan call
+    cache = PlanCache()
     col = obs.TraceCollector()
     with obs.use_collector(col):
-        run_pallas(prog, inputs, cache=PlanCache(), fuse=False)
-    assert "dispatch" in {e.get("cat") for e in col.events}
+        run_pallas(prog, inputs, cache=cache, fuse=False)
+    n_calls = sum(p.calls for p in cache._plans.values())
+    assert n_calls > 1
+    assert [e["cat"] for e in col.events] == ["dispatch"] * n_calls
+
+
+def test_mesh_program_on_one_device_runs_its_step_plan_when_traced():
+    graph = paper_cnn_graph(batch=4, img=8)
+    sharded = shard_training_step(graph, mesh_shape=(2, 2), n_clusters=4)
+    _, _, inputs = _graph_and_inputs(batch=4)
+    cache = PlanCache()
+    col = obs.TraceCollector()
+    with obs.use_collector(col):
+        run_pallas(sharded.program, inputs, cache=cache)
+    steps = [p for p in cache._plans.values() if p.key[0] == "train_step"]
+    assert [p.calls for p in steps] == [1]
+    assert col.spans == []
+
+
+_SHARD_MAP_TRACED = """
+import numpy as np
+from repro import obs
+from repro.lower import PlanCache, paper_cnn_graph, run_pallas, shard_training_step
+graph = paper_cnn_graph(batch=4, img=8)
+sharded = shard_training_step(graph, mesh_shape=(2, 2), n_clusters=4)
+rng = np.random.RandomState(0)
+inputs = {graph.input_edge: rng.randn(4, 8, 8, 3).astype(np.float32),
+          graph.label_edge: np.eye(10, dtype=np.float32)[rng.randint(0, 10, 4)],
+          **graph.init_params(seed=1)}
+plain = run_pallas(sharded.program, inputs, cache=PlanCache())
+col = obs.TraceCollector()
+with obs.use_collector(col):
+    traced = run_pallas(sharded.program, inputs, cache=PlanCache())
+assert col.spans == [], [s.name for s in col.spans]
+assert all(np.array_equal(np.asarray(plain[k]), np.asarray(traced[k])) for k in plain)
+print("ok")
+"""
+
+
+def test_shard_map_step_records_no_span_at_trace_time():
+    """On four (fake) devices the mesh program runs under ``shard_map``:
+    a collector records nothing from inside its body and changes no
+    output."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(root / "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    p = subprocess.run([sys.executable, "-c", _SHARD_MAP_TRACED], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0 and p.stdout.strip() == "ok", p.stderr[-2000:]
+
+
+LEAVES = ["batch", "labels", "dispatch", "wait", "readback", "loss", "commit"]
+
+
+def _cnn_batches(graph, seed=0):
+    rng = np.random.RandomState(seed)
+    shape = (graph.batch,) + tuple(graph.input_shape)
+
+    def batch_fn(_i):
+        return (rng.randn(*shape).astype(np.float32),
+                rng.randint(0, graph.loss.classes, graph.batch))
+
+    return batch_fn
+
+
+def test_train_graph_records_step_spans_and_counters():
+    import time
+
+    # a step long enough that the spans' own bookkeeping (microseconds)
+    # is a small share of it
+    graph = paper_cnn_graph(batch=8, img=64)
+    params = graph.init_params(seed=1)
+    col = obs.TraceCollector()
+    t_start = time.time_ns()
+    with obs.use_collector(col):
+        res = train_graph(graph, 2, _cnn_batches(graph), params=params,
+                          cache=PlanCache())
+    t_end = time.time_ns()
+    spans = col.host_spans()
+    steps = [s for s in spans if s[0] == "step"]
+    assert [s[3] for s in steps] == [0, 1]
+    by_id = {s.id: s for s in col.spans}
+    for i, (_, s0, s1, _, args) in enumerate(steps):
+        # wall-clock nanoseconds, the clock of the profiler's events
+        assert t_start <= s0 < s1 <= t_end
+        leaves = [s for s in col.spans if s.parent is not None
+                  and by_id[s.parent].name == "step" and s.step == i]
+        leaves.sort(key=lambda s: s.id)
+        assert [s.name for s in leaves] == LEAVES
+        assert all(a.t1_ns <= b.t0_ns for a, b in zip(leaves, leaves[1:]))
+        covered = sum(s.t1_ns - s.t0_ns for s in leaves)
+        assert covered >= 0.95 * (s1 - s0)
+        assert args["step"] == i
+        assert (args["plan_retraces"] > 0) == (i == 0)  # the first step compiles
+    x_bytes = graph.batch * int(np.prod(graph.input_shape)) * 4
+    onehot_bytes = graph.batch * graph.loss.classes * 4
+    param_bytes = sum(v.nbytes for v in params.values())
+    # params go to the device once; after that they stay there
+    assert steps[0][4]["h2d_bytes"] == x_bytes + onehot_bytes + param_bytes
+    assert steps[1][4]["h2d_bytes"] == x_bytes + onehot_bytes
+    assert all(s[4]["d2h_bytes"] == graph.batch * graph.loss.classes * 4
+               for s in steps)
+    assert len(res["losses"]) == 2
+    # the chrome export rebases the host lanes to zero
+    host = [e for e in col.to_chrome_trace()["traceEvents"] if e["pid"] == "host"]
+    assert min(e["ts"] for e in host) == 0.0
+
+
+def test_train_graph_spans_add_no_plan_cache_miss_or_trace():
+    graph = paper_cnn_graph(batch=2, img=8)
+    program = lower_training_step(graph, n_clusters=4)
+    cache = PlanCache()
+    batches = _cnn_batches(graph)
+    res = train_graph(graph, 1, batches, program=program, cache=cache)
+    hits, misses, traces, calls = executors._cache_stats(cache)
+    col = obs.TraceCollector()
+    with obs.use_collector(col):
+        train_graph(graph, 1, batches, program=program, cache=cache,
+                    params=res["params"])
+    h2, m2, t2, c2 = executors._cache_stats(cache)
+    assert (m2, t2) == (misses, traces)
+    assert (h2, c2) == (hits + 1, calls + 1)  # one call of the step plan
+    (step,) = [s for s in col.host_spans() if s[0] == "step"]
+    assert step[4]["plan_retraces"] == 0
+
+
+def test_host_spans_nest_and_inherit_the_step():
+    col = obs.TraceCollector()
+    with col.host_span("step", step=3) as args:
+        with col.host_span("inner"):
+            pass
+        args["n"] = 1
+    with col.host_span("free"):
+        pass
+    assert [(n, st, a) for n, _, _, st, a in col.host_spans()] == [
+        ("step", 3, {"n": 1}), ("inner", 3, {}), ("free", None, {})]
+    outer, inner, free = sorted(col.spans, key=lambda s: s.id)
+    assert inner.parent == outer.id and outer.parent is None and free.parent is None
+    assert outer.t0_ns <= inner.t0_ns <= inner.t1_ns <= outer.t1_ns
 
 
 def test_block_spans_cover_commands():

@@ -13,6 +13,7 @@ case that needs it lives in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -87,6 +88,19 @@ def _kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _kernel_names(compiled) -> list[tuple[str, str]]:
+    """(instruction name, op_name metadata) of each kernel in the compiled
+    program: the benchmark's trace reduction finds kernels by the first,
+    the walk's ``jax.named_scope`` lands in the second."""
+    out = []
+    for line in compiled.as_text().splitlines():
+        if "tpu_custom_call" in line:
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            out.append((line.split("=", 1)[0].strip().lstrip("%"),
+                        op_name.group(1) if op_name else ""))
+    return out
+
+
 def _step_shapes(graph) -> dict:
     """Shapes of a train step's inputs: the input edge, labels, params."""
     if isinstance(graph.nodes[0].spec, Conv2dSpec):
@@ -127,6 +141,10 @@ def test_paper_cnn_fused_step_compiles(compile_for):
     graph = paper_cnn_graph(batch=8, img=16, lr=0.05, momentum=0.9)
     compiled = compile_for(_fused_step(graph), _step_shapes(graph))
     assert _kernels(compiled) == 1  # the whole step is one region kernel
+    ((name, op_name),) = _kernel_names(compiled)
+    # named after its region, under the region's scope
+    assert re.fullmatch(r"fused_c1_fwd__c1_upd\.\d+", name), name
+    assert "/fused[c1:fwd..c1:upd]x" in op_name, op_name
 
 
 @pytest.mark.parametrize("pass_", ["fwd", "dw", "dx"])
@@ -152,5 +170,11 @@ def test_qwen_width_lm_step_compiles(compile_for):
     compiled = compile_for(_fused_step(graph), _step_shapes(graph))
     # every matmul pass of the step is a streaming kernel
     assert _kernels(compiled) == 17
+    names = _kernel_names(compiled)
+    # the roofline readers find the kernel as `streaming_matmul.<n>`, and
+    # each sits under the scope of its node and pass
+    assert all(re.fullmatch(r"streaming_matmul\.\d+", n) for n, _ in names), names
+    scopes = {re.search(r"/(\w+:(fwd|dw|dx))/", o).group(1) for _, o in names}
+    assert {"qkv_0:fwd", "fc1_0:dw", "head:dx"} <= scopes, scopes
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes < 16e9
