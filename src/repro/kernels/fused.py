@@ -59,6 +59,22 @@ def _dot(a, b):
     )
 
 
+def softmax_xent(z, onehot, batch: int):
+    """Softmax cross-entropy over the last axis: ``(dz, rows)``.
+
+    ``dz = (softmax(z) - onehot) / batch`` is the loss gradient, with the
+    softmax spelled as ``jax.nn.softmax`` spells it; ``rows`` is each
+    row's loss ``logsumexp(z) - z[target]`` from the same max and sum,
+    kept as a column (trailing axis of one) so that a kernel tile stores
+    it without a shape cast.
+    """
+    zm = z - jnp.max(z, axis=-1, keepdims=True)
+    e = jnp.exp(zm)
+    s = jnp.sum(e, axis=-1, keepdims=True)
+    rows = jnp.log(s) - jnp.sum(zm * onehot, axis=-1, keepdims=True)
+    return (e / s - onehot) / batch, rows
+
+
 def _batch_block(batch: int, ranks) -> int:
     """Batch-tile size: two grid steps when the batch splits evenly.
 
@@ -284,14 +300,13 @@ def _stage_flow(region, env):
             partials[f"d_{st.param}"] = d
         elif st.pass_ == "dx":
             if isinstance(s, SoftmaxXentSpec):
-                # softmax-CE loss gradient (softmax(z) - onehot) / B: rows
-                # are independent, so the batch-tile split is exact; the
-                # 1/B scale uses the spec's global batch, and the onehot
-                # labels arrive via the stage's param slot
-                z = env[st.in_edge]
-                env[f"d_{st.in_edge}"] = (
-                    jax.nn.softmax(z, axis=-1) - env[st.param]
-                ) / s.batch
+                # softmax-CE loss gradient (softmax(z) - onehot) / B and
+                # each row's loss: rows are independent, so the batch-tile
+                # split is exact; the 1/B scale uses the spec's global
+                # batch, and the onehot labels arrive via the stage's
+                # param slot
+                env[f"d_{st.in_edge}"], env[st.out_edge] = softmax_xent(
+                    env[st.in_edge], env[st.param], s.batch)
                 continue
             g = env[f"d_{st.out_edge}"]
             if isinstance(s, Conv2dSpec):

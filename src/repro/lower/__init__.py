@@ -55,7 +55,6 @@ from repro.lower.graph import (
     lower_training_step,
     one_hot_rows,
     paper_cnn_graph,
-    softmax_xent_loss,
     train_graph,
 )
 from repro.lower.mesh import (
@@ -141,7 +140,6 @@ __all__ = [
     "one_hot_rows",
     "paper_cnn_graph",
     "register_lowering",
-    "softmax_xent_loss",
     "supported_matrix",
     "train_graph",
 ]

@@ -78,7 +78,10 @@ def run_reference(
 
     ``inputs`` maps region names (kind "input"/"param") to arrays of the
     region's shape. Scratch regions are staged by the program's own
-    memset/copy commands — no out-of-band padding happens here.
+    memset/copy commands — no out-of-band padding happens here. A
+    train-step program's outputs also carry ``graph.loss_rows_edge``: each
+    logits row's cross-entropy, which the command stream leaves to the
+    host, computed here in float64 from the logits and one-hot labels.
     """
     mem = np.zeros(program.memory_words, np.float32)
     needed = {r.name for r in program.regions.values() if r.kind in ("input", "param")}
@@ -94,10 +97,25 @@ def run_reference(
     for cmd in program.commands():
         ntx_execute(cmd, mem, wide=wide, vectorize=vectorize, inplace=True)
     obs.record_program(obs.get_active(), program)
-    return {
+    outs = {
         r.name: mem[r.base : r.end].reshape(r.shape).copy()
         for r in program.regions_of_kind("output")
     }
+    if program.meta.get("pass") == "train_step":
+        graph = program.meta["graph"]
+        outs[graph.loss_rows_edge] = _xent_rows(
+            outs[graph.logits_edge], inputs[graph.label_edge])
+    return outs
+
+
+def _xent_rows(logits, onehot) -> np.ndarray:
+    """float64 softmax cross-entropy of each logits row against its
+    one-hot label row."""
+    y = np.asarray(onehot, np.float64)
+    z = np.asarray(logits, np.float64).reshape(-1, y.shape[-1])
+    z = z - z.max(axis=1, keepdims=True)
+    lse = np.log(np.exp(z).sum(axis=1))
+    return lse - (z * y.reshape(z.shape)).sum(axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -281,11 +299,11 @@ def _plan_callable(spec, pass_: str, interpret: bool):
 
     if isinstance(spec, SoftmaxXentSpec):
         if pass_ == "dx":
-            B = spec.batch
+            from repro.kernels.fused import softmax_xent
 
             def xent_dx(j):
-                p = jax.nn.softmax(j["z"], axis=-1)
-                return {"dz": (p - j["onehot"]) / B}
+                dz, rows = softmax_xent(j["z"], j["onehot"], spec.batch)
+                return {"dz": dz, "rows": rows.reshape(-1)}
 
             return xent_dx
 
@@ -667,7 +685,11 @@ def run_pallas(
     Fused train-step programs execute as ONE cached jitted callable per
     step (the region kernels inline into it at trace time), so the warm
     path is a single dispatch — the executor analogue of the paper's
-    "one offload per training step" goal.
+    "one offload per training step" goal. A train step's outputs also
+    carry ``graph.loss_rows_edge``: each logits row's float32
+    cross-entropy, computed beside the loss gradient on every route (fused,
+    per-node, ``shard_map``), so the host reads one float per row and
+    never the logits.
 
     ``fuse`` (train-step programs only) routes the graph walk through the
     :mod:`repro.lower.fuse` region plan — whole fwd/bwd chains as single
@@ -722,8 +744,8 @@ def _run_pallas_graph(program, inputs, interpret: bool, cache, fuse=True,
     single region kernels, everything else through the cached per-node
     plans (per-image nodes key as :class:`BatchedSpec`). Outputs carry the
     program's output-region names — logits, ``d_<param>`` (when kept),
-    ``<param>_new`` and ``v_<param>_new`` — so callers are
-    executor-agnostic.
+    ``<param>_new`` and ``v_<param>_new`` — plus the loss rows, like
+    :func:`run_reference`'s, so callers are executor-agnostic.
     """
     graph = program.meta["graph"]
     keep_grads = program.meta.get("keep_grads", True)
@@ -836,9 +858,11 @@ def _graph_step_local(graph, j, plan, B, *, keep_grads=True,
     # accumulate one contribution per consumer (matching the compiled
     # program's partial + accumulate-step schedule)
     with jax.named_scope("loss:dx"):
-        grads = {graph.logits_edge: plan(graph.loss, "dx")(
+        loss = plan(graph.loss, "dx")(
             {"z": logits, "onehot": j[graph.label_edge]}
-        )["dz"]}
+        )
+    outs[graph.loss_rows_edge] = loss["rows"]
+    grads = {graph.logits_edge: loss["dz"]}
 
     def add_grad(edge, v):
         grads[edge] = grads[edge] + v if edge in grads else v
@@ -948,9 +972,11 @@ def _walk_fused(graph, j, plan, B, fusion, *, keep_grads, reduce, batched):
             # left to do
             return
         if name == "loss":
-            env[f"d_{graph.logits_edge}"] = plan(graph.loss, "dx")(
+            loss = plan(graph.loss, "dx")(
                 {"z": env[graph.logits_edge], "onehot": j[graph.label_edge]}
-            )["dz"]
+            )
+            env[f"d_{graph.logits_edge}"] = loss["dz"]
+            env[graph.loss_rows_edge] = loss["rows"]
             return
         node = nodes[name]
         s = node.spec
@@ -1091,6 +1117,8 @@ def _walk_fused(graph, j, plan, B, fusion, *, keep_grads, reduce, batched):
             else:  # <param>_new / v_<param>_new epilogue results
                 outs[name] = v
     outs[graph.logits_edge] = env[graph.logits_edge]
+    # a region's loss stage leaves its rows as a column
+    outs[graph.loss_rows_edge] = env[graph.loss_rows_edge].reshape(-1)
     return outs, env
 
 
@@ -1187,7 +1215,9 @@ def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
         return P(dp_axes) if name in sharded_edges else P()
 
     in_specs = ({k: batch_spec(k) for k in j},)
-    out_specs = {graph.logits_edge: P(dp_axes)}
+    # the shards' logits and loss rows concatenate in batch order
+    out_specs = {graph.logits_edge: P(dp_axes),
+                 graph.loss_rows_edge: P(dp_axes)}
     for p in graph.param_shapes():
         out_specs[f"{p}_new"] = P()
         if keep_grads:

@@ -23,12 +23,13 @@ Steps with no fusion rule — the maxpool-dX winner scatter, steps touching
 spilled regions, the DAG fan-out accumulate steps — become per-node
 fallback :class:`Segment`s, so the fused walk stays numerically compatible
 with ``run_reference`` on every graph. The softmax-CE loss gradient
-``(softmax(z) - onehot) / B`` is row-independent and fuses like any other
-stage, stitching the forward head chain to the backward dW chain. LM/DAG
-graphs (attention, layernorm, residual fan-out) carry *token-row*
-activations — ``B*S`` rows, not ``B`` — which the batch-tile grid of the
-region kernel cannot stream correctly, so their activation passes all run
-as fallbacks; only the SGD update epilogues fuse there.
+``(softmax(z) - onehot) / B``, with each row's loss beside it, is
+row-independent and fuses like any other stage, stitching the forward
+head chain to the backward dW chain. LM/DAG graphs (attention, layernorm,
+residual fan-out) carry *token-row* activations — ``B*S`` rows, not ``B``
+— which the batch-tile grid of the region kernel cannot stream correctly,
+so their activation passes all run as fallbacks; only the SGD update
+epilogues fuse there.
 
 Each region's intermediate edges stay resident in kernel scratch; only
 edges read by steps outside the region (or program outputs) escape. The
@@ -206,7 +207,7 @@ def _step_io(graph, node, pass_: str, *, fused: bool):
     if node is None:  # loss:dx
         return (
             [graph.logits_edge, graph.label_edge],
-            [f"d_{graph.logits_edge}"],
+            [f"d_{graph.logits_edge}", graph.loss_rows_edge],
         )
     s = node.spec
     if pass_ == "fwd":
@@ -329,7 +330,7 @@ def plan_fusion(program, *, fuse_updates: bool = True) -> FusionPlan:
                         pass_="dx",
                         spec=graph.loss,
                         in_edge=graph.logits_edge,
-                        out_edge=graph.logits_edge,
+                        out_edge=graph.loss_rows_edge,
                         param=graph.label_edge,
                     )
                 )
@@ -365,7 +366,7 @@ def plan_fusion(program, *, fuse_updates: bool = True) -> FusionPlan:
         node = nodes.get(name) if name != "loss" else None
         io[key] = _step_io(graph, node, pass_, fused=key_fused[key])
 
-    program_outputs = {graph.logits_edge}
+    program_outputs = {graph.logits_edge, graph.loss_rows_edge}
     for p in graph.param_shapes():
         program_outputs.add(f"{p}_new")
         if keep_grads:

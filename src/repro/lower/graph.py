@@ -348,6 +348,11 @@ class NetworkGraph:
     def logits_edge(self) -> str:
         return self.nodes[-1].out_edge
 
+    @property
+    def loss_rows_edge(self) -> str:
+        """The step output holding each logits row's cross-entropy."""
+        return "loss_rows"
+
     def param_nodes(self) -> list[GraphNode]:
         return [n for n in self.nodes if n.param is not None]
 
@@ -1198,14 +1203,6 @@ def lm_token_batches(
     return batch_fn
 
 
-def softmax_xent_loss(logits: np.ndarray, labels: np.ndarray) -> float:
-    """Host-side scalar loss over the program's logits output."""
-    z = np.asarray(logits, np.float64)
-    z = z - z.max(axis=1, keepdims=True)
-    lse = np.log(np.exp(z).sum(axis=1))
-    return float(np.mean(lse - z[np.arange(len(labels)), labels]))
-
-
 #: what a span site of :func:`train_graph` enters when no collector is
 #: installed
 _NO_SPAN = nullcontext()
@@ -1235,7 +1232,10 @@ def train_graph(
     ``backend`` is ``"pallas"`` (graph-driven plan-cache execution) or
     ``"reference"`` (the numpy command interpreter). Every step runs the
     SAME program — parameters round-trip through the ``*_new`` outputs.
-    The result carries per-step wall-clock seconds in ``"walls"``.
+    A step's loss is the float64 mean of the cross-entropy rows the
+    executor returns (``graph.loss_rows_edge``); the logits stay where the
+    executor left them. The result carries per-step wall-clock seconds in
+    ``"walls"``.
 
     ``registry`` (a :class:`repro.obs.CounterRegistry`) is installed for
     the loop; each step records under a ``step{i}`` scope, so per-step
@@ -1256,12 +1256,12 @@ def train_graph(
 
     With a :class:`repro.obs.trace.TraceCollector` installed, each step
     records a ``step`` span (args ``step``; counters ``h2d_bytes``, the
-    host arrays handed to the executor, ``d2h_bytes``, the logits read
+    host arrays handed to the executor, ``d2h_bytes``, the loss rows read
     back, and ``plan_retraces``, the plan cache's traces during the step)
     over the leaf spans ``batch`` (``batch_fn``), ``labels`` (the one-hot
     rows), ``dispatch`` (the executor call until it returns), ``wait``
-    (until the device is done), ``readback`` (logits to numpy), ``loss``
-    (the float64 host loss) and ``commit`` (the ``*_new`` params, the
+    (until the device is done), ``readback`` (the loss rows to numpy),
+    ``loss`` (their float64 mean) and ``commit`` (the ``*_new`` params, the
     metrics writer, the chaos hook). The step that runs is the same with
     or without a collector.
     """
@@ -1341,9 +1341,9 @@ def train_graph(
                             i = action.resume_step
                             continue
                     with span("readback"):
-                        logits = np.asarray(outs[graph.logits_edge])
+                        rows = np.asarray(outs[graph.loss_rows_edge])
                     with span("loss"):
-                        losses.append(softmax_xent_loss(logits, labels))
+                        losses.append(float(np.mean(np.asarray(rows, np.float64))))
                     with span("commit"):
                         # keep updated params as whatever the backend returned
                         # (jax arrays stay on device between pallas steps — no
@@ -1368,7 +1368,7 @@ def train_graph(
                         counters["h2d_bytes"] = sum(
                             v.nbytes for v in inputs.values()
                             if isinstance(v, np.ndarray))
-                        counters["d2h_bytes"] = logits.nbytes
+                        counters["d2h_bytes"] = rows.nbytes
                         counters["plan_retraces"] = (
                             executors._cache_stats(plan_cache)[2] - traces0)
                 i += 1

@@ -138,8 +138,9 @@ class SoftmaxXentSpec:
     """Softmax-cross-entropy over (batch, classes) logits.
 
     Only the gradient pass lowers (``dx``): dz = (softmax(z) - onehot) / B,
-    staged entirely in-band (max/exp/sum/recip command blocks). The scalar
-    loss value stays on the driver core — executors read it off the logits.
+    staged entirely in-band (max/exp/sum/recip command blocks). The loss
+    value stays off the command stream: executors return each row's
+    cross-entropy beside the step's outputs (``loss_rows``).
     """
 
     batch: int

@@ -379,12 +379,24 @@ def test_train_graph_records_step_spans_and_counters():
     # params go to the device once; after that they stay there
     assert steps[0][4]["h2d_bytes"] == x_bytes + onehot_bytes + param_bytes
     assert steps[1][4]["h2d_bytes"] == x_bytes + onehot_bytes
-    assert all(s[4]["d2h_bytes"] == graph.batch * graph.loss.classes * 4
-               for s in steps)
+    # the host reads back one float32 loss per row, never the logits
+    assert all(s[4]["d2h_bytes"] == graph.batch * 4 for s in steps)
     assert len(res["losses"]) == 2
     # the chrome export rebases the host lanes to zero
     host = [e for e in col.to_chrome_trace()["traceEvents"] if e["pid"] == "host"]
     assert min(e["ts"] for e in host) == 0.0
+
+
+@pytest.mark.parametrize("n_classes", [10, 1000])
+def test_train_graph_reads_back_the_loss_rows_whatever_the_classes(n_classes):
+    """``d2h_bytes`` counts the loss rows: a float32 per row, the same
+    at 10 classes and at 1000."""
+    graph = paper_cnn_graph(batch=2, img=8, n_classes=n_classes)
+    col = obs.TraceCollector()
+    with obs.use_collector(col):
+        train_graph(graph, 1, _cnn_batches(graph), cache=PlanCache())
+    (step,) = [s for s in col.host_spans() if s[0] == "step"]
+    assert step[4]["d2h_bytes"] == graph.batch * 4
 
 
 def test_train_graph_spans_add_no_plan_cache_miss_or_trace():
