@@ -31,6 +31,7 @@ addresses.
 
 from __future__ import annotations
 
+import functools
 import math
 from contextlib import nullcontext
 from dataclasses import dataclass, field
@@ -1179,6 +1180,40 @@ def one_hot_rows(idx, n_classes: int) -> np.ndarray:
     return rows
 
 
+def _one_hot(ids, n_classes: int):
+    """:func:`one_hot_rows` traced for the device: exactly 1.0 and 0.0."""
+    import jax.numpy as jnp
+
+    return (ids[:, None] == jnp.arange(n_classes, dtype=ids.dtype)).astype(jnp.float32)
+
+
+@functools.cache
+def _one_hot_jit():
+    """:func:`_one_hot` jitted once per process, ``n_classes`` static: every
+    ``train_graph`` call at the same shapes reuses one executable."""
+    import jax
+
+    return jax.jit(_one_hot, static_argnums=1)
+
+
+def device_one_hot_rows(idx, n_classes: int):
+    """:func:`one_hot_rows` built on the device: the ids are checked on the
+    host and cross as int32 (4 bytes a row), the float32 rows never do.
+
+    Returns ``(rows, ids)``: the (N, n_classes) float32 ``jax.Array``, equal
+    bit for bit to ``one_hot_rows(idx, n_classes)``, and the int32 host ids
+    it was built from. Raises ``ValueError`` on an id outside
+    ``[0, n_classes)``, which numpy would wrap and jax would leave a zero
+    row."""
+    ids = np.asarray(idx).reshape(-1)
+    if ids.min() < 0 or ids.max() >= n_classes:
+        bad = ids[(ids < 0) | (ids >= n_classes)]
+        raise ValueError(
+            f"label ids must lie in [0, {n_classes}); got {bad[:8].tolist()}")
+    ids = ids.astype(np.int32)
+    return _one_hot_jit()(ids, n_classes), ids
+
+
 def lm_token_batches(
     rng: np.random.RandomState, batch: int, seq: int, vocab: int
 ) -> Callable[[int], tuple[np.ndarray, np.ndarray]]:
@@ -1256,10 +1291,12 @@ def train_graph(
 
     With a :class:`repro.obs.trace.TraceCollector` installed, each step
     records a ``step`` span (args ``step``; counters ``h2d_bytes``, the
-    host arrays handed to the executor, ``d2h_bytes``, the loss rows read
-    back, and ``plan_retraces``, the plan cache's traces during the step)
-    over the leaf spans ``batch`` (``batch_fn``), ``labels`` (the one-hot
-    rows), ``dispatch`` (the executor call until it returns), ``wait``
+    host arrays handed to the executor and the int32 label ids,
+    ``d2h_bytes``, the loss rows read back, and ``plan_retraces``, the plan
+    cache's traces during the step) over the leaf spans ``batch``
+    (``batch_fn``), ``labels`` (the ids checked and their one-hot rows built
+    on the device, :func:`device_one_hot_rows`), ``dispatch`` (the executor
+    call until it returns), ``wait``
     (until the device is done), ``readback`` (the loss rows to numpy),
     ``loss`` (their float64 mean) and ``commit`` (the ``*_new`` params, the
     metrics writer, the chaos hook). The step that runs is the same with
@@ -1309,7 +1346,8 @@ def train_graph(
                         x, labels = batch_fn(i)
                         x = np.asarray(x, np.float32)
                     with span("labels"):
-                        onehot = one_hot_rows(labels, graph.loss.classes)
+                        onehot, ids = device_one_hot_rows(
+                            labels, graph.loss.classes)
                     inputs = {graph.input_edge: x, graph.label_edge: onehot, **params}
                     step_scope = (
                         reg.scope(f"step{i}") if reg is not None else nullcontext()
@@ -1365,7 +1403,7 @@ def train_graph(
                         if chaos is not None:
                             chaos.committed(i, params)
                     if col is not None:
-                        counters["h2d_bytes"] = sum(
+                        counters["h2d_bytes"] = ids.nbytes + sum(
                             v.nbytes for v in inputs.values()
                             if isinstance(v, np.ndarray))
                         counters["d2h_bytes"] = rows.nbytes

@@ -374,11 +374,12 @@ def test_train_graph_records_step_spans_and_counters():
         assert args["step"] == i
         assert (args["plan_retraces"] > 0) == (i == 0)  # the first step compiles
     x_bytes = graph.batch * int(np.prod(graph.input_shape)) * 4
-    onehot_bytes = graph.batch * graph.loss.classes * 4
+    # the label ids cross as int32; their one-hot rows are built on the device
+    ids_bytes = graph.batch * 4
     param_bytes = sum(v.nbytes for v in params.values())
     # params go to the device once; after that they stay there
-    assert steps[0][4]["h2d_bytes"] == x_bytes + onehot_bytes + param_bytes
-    assert steps[1][4]["h2d_bytes"] == x_bytes + onehot_bytes
+    assert steps[0][4]["h2d_bytes"] == x_bytes + ids_bytes + param_bytes
+    assert steps[1][4]["h2d_bytes"] == x_bytes + ids_bytes
     # the host reads back one float32 loss per row, never the logits
     assert all(s[4]["d2h_bytes"] == graph.batch * 4 for s in steps)
     assert len(res["losses"]) == 2
