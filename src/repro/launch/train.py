@@ -431,7 +431,8 @@ def run_ntx_cnn(steps: int, batch: int, img: int, *, n_clusters: int = 16,
     ``fuse`` (default) executes whole-step programs through the region
     fuser — chains of compatible layers as single double-buffered Pallas
     kernels, one cached step-level plan per program. ``fuse=False``
-    (``--no-fuse``) is the escape hatch back to per-node plan dispatch.
+    (``--no-fuse``) is the escape hatch: the same graph walk over a plan
+    with no regions, every node pass's plan dispatched from the host.
 
     ``chaos`` injects faults (:class:`repro.runtime.faults.ChaosSchedule`
     grammar, e.g. ``"kill:hmc=1@step=2"``): a killed cube's step is

@@ -27,7 +27,9 @@ gradient-allreduce epilogue, bit-identical under ``run_reference`` and
 On the Pallas path, whole-step programs route through the region fuser
 (:mod:`repro.lower.fuse`): contiguous compatible chains execute as single
 double-buffered fused kernels and the step compiles to ONE cached
-callable; ``run_pallas(..., fuse=False)`` is the per-node escape hatch.
+callable; ``run_pallas(..., fuse=False)`` is the per-node escape hatch:
+the same graph walk over a plan with no regions, every node pass's plan
+dispatched from the host.
 
 See docs/architecture.md ("The lowering pipeline", "The graph compiler",
 "Mesh execution", "The region fuser").

@@ -23,11 +23,14 @@ lowering rule, not three backend implementations.
 
 from __future__ import annotations
 
+import functools
+from dataclasses import dataclass, replace
+from typing import Callable
+
 import numpy as np
 
-from dataclasses import dataclass
-
 from repro.core.ntx import ntx_execute
+from repro.lower.fuse import RegionSpec, plan_fusion, unfused_plan
 from repro.lower.ir import NtxProgram
 from repro.obs import counters as obs
 from repro.obs import trace as obs_trace
@@ -162,12 +165,76 @@ def run_timing(
 # ---------------------------------------------------------------------------
 
 
-def _conv_plan(spec: Conv2dSpec, pass_: str, interpret: bool):
-    """One conv pass over a batch of images (N, H, W, C) in the streaming
-    kernel, or None for a pass the conv has no route for.
+#: spec type -> its entry in the Pallas executor, one per node type:
+#: {"plans": fn, "fwd" | "dw" | "dx": wiring} (see :func:`_route`)
+_ROUTES: dict[type, dict[str, Callable]] = {}
 
-    The images fold into the matmul rows, so dW comes out summed over the
-    batch (the images join the contraction, as in the fused region).
+
+def _route(spec_type: type, **wiring):
+    """Decorator: register ``plans(spec, interpret)`` and ``wiring`` as
+    ``spec_type``'s entry.
+
+    ``plans`` maps each pass the type runs as one plan to its pure jax
+    callable ``dict[str, Array] -> dict[str, Array]`` (what
+    :class:`PlanCache` jits). ``fwd(wk, node, x)``, ``dw(wk, node, dy)`` and
+    ``dx(wk, node, dy)`` wire a node into the walk ``wk`` (ports, reshapes,
+    plan key) and return its output, parameter gradient and input
+    gradient. The loss gradient and the SGD update belong to the walk.
+    """
+
+    def deco(plans):
+        _ROUTES[spec_type] = {"plans": plans, **wiring}
+        return plans
+
+    return deco
+
+
+class _Walk:
+    """What one step walk hands the wiring: the step's inputs ``j``, the
+    edge environment ``env`` (gradient of edge ``e`` at ``d_<e>``), and a
+    leading batch axis of ``B`` on the activations when ``batched``."""
+
+    def __init__(self, j, plan, B, batched):
+        self.j, self.plan, self.B, self.batched = j, plan, B, batched
+        self.env = {}
+
+    def act(self, node):
+        return self.env[node.in_edge]
+
+    def param(self, node):
+        return self.j[node.param]
+
+    def per_image(self, spec):
+        """The plan key of a per-image spec over the walk's batch."""
+        return BatchedSpec(spec, self.B) if self.batched else spec
+
+    def add_grad(self, edge, v):
+        """Add one consumer's contribution to the gradient of ``edge``."""
+        key = f"d_{edge}"
+        self.env[key] = self.env[key] + v if key in self.env else v
+
+
+@_route(
+    MatmulSpec,
+    fwd=lambda wk, n, x: wk.plan(n.spec, "fwd")({"a": x, "b": wk.param(n)})["c"],
+    dw=lambda wk, n, dy: wk.plan(n.spec, "dw")({"a": wk.act(n), "dy": dy})["dw"],
+    dx=lambda wk, n, dy: wk.plan(n.spec, "dx")({"dy": dy, "b": wk.param(n)})["dx"],
+)
+def _matmul_plans(spec: MatmulSpec, interpret: bool):
+    from repro.kernels import streaming
+
+    mm = functools.partial(streaming.streaming_matmul, interpret=interpret)
+    return {
+        "fwd": lambda j: {"c": mm(j["a"], j["b"])},
+        "dw": lambda j: {"dw": mm(j["a"].T, j["dy"])},
+        "dx": lambda j: {"dx": mm(j["dy"], j["b"].T)},
+    }
+
+
+def _conv_plans(spec: Conv2dSpec, interpret: bool):
+    """A conv's passes over a batch of images (N, H, W, C) in the streaming
+    kernel. The images fold into the matmul rows, so dW comes out summed
+    over the batch (the images join the contraction, as in the fused region).
     """
     import jax.numpy as jnp
 
@@ -175,49 +242,288 @@ def _conv_plan(spec: Conv2dSpec, pass_: str, interpret: bool):
     from repro.kernels import streaming
 
     s, p = spec.stride, spec.padding
-    if pass_ == "fwd":
-        return lambda j: {
-            "y": streaming.streaming_conv2d(
-                j["x"], j["w"], stride=s, padding=p, interpret=interpret
-            )
-        }
-    if pass_ == "dw":
-        # dW = cols(x)^T @ dy: the same im2col the forward kernel streams,
-        # with the (n*oh*ow) output pixels as the contraction dim.
-        def dw(j):
-            x = j["x"]
-            if p:
-                x = jnp.pad(x, ((0, 0), (p, p), (p, p), (0, 0)))
-            oh, ow = spec.out_h, spec.out_w
-            cols = jnp.concatenate(
-                [
-                    x[:, dh : dh + oh * s : s, dw_ : dw_ + ow * s : s, :]
-                    for dh in range(spec.kh)
-                    for dw_ in range(spec.kw)
-                ],
-                axis=-1,
-            ).reshape(-1, spec.kh * spec.kw * spec.cin)
-            dyf = j["dy"].reshape(-1, spec.cout)
-            dw_flat = streaming.streaming_matmul(cols.T, dyf, interpret=interpret)
-            return {"dw": dw_flat.reshape(spec.kh, spec.kw, spec.cin, spec.cout)}
+    conv = functools.partial(streaming.streaming_conv2d, interpret=interpret)
 
-        return dw
-    if pass_ == "dx":
-        # The §3.2 phase decomposition with the dense per-phase conv
-        # routed through the streaming Pallas kernel.
-        def conv_fn(dy, w_ab, pads):
-            ph, pw = pads
-            dyp = jnp.pad(dy, ((0, 0), (ph, ph), (pw, pw), (0, 0)))
-            return streaming.streaming_conv2d(
-                dyp, w_ab, stride=1, padding=0, interpret=interpret
-            )
+    # dW = cols(x)^T @ dy: the same im2col the forward kernel streams,
+    # with the (n*oh*ow) output pixels as the contraction dim.
+    def dw(j):
+        x = j["x"]
+        if p:
+            x = jnp.pad(x, ((0, 0), (p, p), (p, p), (0, 0)))
+        oh, ow = spec.out_h, spec.out_w
+        taps = [x[:, dh : dh + oh * s : s, dw_ : dw_ + ow * s : s, :]
+                for dh in range(spec.kh) for dw_ in range(spec.kw)]
+        cols = jnp.concatenate(taps, axis=-1).reshape(-1, spec.kh * spec.kw * spec.cin)
+        dyf = j["dy"].reshape(-1, spec.cout)
+        dw_flat = streaming.streaming_matmul(cols.T, dyf, interpret=interpret)
+        return {"dw": dw_flat.reshape(spec.kh, spec.kw, spec.cin, spec.cout)}
 
-        return lambda j: {
-            "dx": conv_decomp.conv2d_input_grad_decomposed(
-                j["dy"], j["w"], s, (spec.in_h, spec.in_w), p, conv_fn=conv_fn
-            )
-        }
-    return None
+    # The §3.2 phase decomposition with the dense per-phase conv
+    # routed through the streaming Pallas kernel.
+    def conv_fn(dy, w_ab, pads):
+        ph, pw = pads
+        dyp = jnp.pad(dy, ((0, 0), (ph, ph), (pw, pw), (0, 0)))
+        return conv(dyp, w_ab, stride=1, padding=0)
+
+    return {
+        "fwd": lambda j: {"y": conv(j["x"], j["w"], stride=s, padding=p)},
+        "dw": dw,
+        "dx": lambda j: {"dx": conv_decomp.conv2d_input_grad_decomposed(
+            j["dy"], j["w"], s, (spec.in_h, spec.in_w), p, conv_fn=conv_fn)},
+    }
+
+
+@_route(
+    Conv2dSpec,
+    fwd=lambda wk, n, x: wk.plan(wk.per_image(n.spec), "fwd")({"x": x, "w": wk.param(n)})["y"],
+    dw=lambda wk, n, dy: wk.plan(wk.per_image(n.spec), "dw")({"x": wk.act(n), "dy": dy})["dw"],
+    dx=lambda wk, n, dy: wk.plan(wk.per_image(n.spec), "dx")({"dy": dy, "w": wk.param(n)})["dx"],
+)
+def _conv_one_image_plans(spec: Conv2dSpec, interpret: bool):
+    # one image: lend it a batch axis of one and take it back off
+    # (dW has no batch axis to remove)
+    def one_image(conv):
+        def run(j):
+            out = conv({k: v if k == "w" else v[None] for k, v in j.items()})
+            return {k: v if k == "dw" else v[0] for k, v in out.items()}
+
+        return run
+
+    return {p: one_image(f) for p, f in _conv_plans(spec, interpret).items()}
+
+
+@_route(
+    MaxPool2dSpec,
+    fwd=lambda wk, n, x: wk.plan(wk.per_image(n.spec), "fwd")({"x": x})["y"],
+    dx=lambda wk, n, dy: wk.plan(wk.per_image(n.spec), "dx")({"x": wk.act(n), "dy": dy})["dx"],
+)
+def _maxpool_plans(spec: MaxPool2dSpec, interpret: bool):
+    import jax
+    import jax.numpy as jnp
+
+    w, s = spec.window, spec.stride
+
+    def pool_fwd(x):
+        return jax.lax.reduce_window(
+            x, -jnp.inf, jax.lax.max, (w, w, 1), (s, s, 1), "VALID"
+        )
+
+    def pool_dx(j):
+        _, vjp = jax.vjp(pool_fwd, j["x"])
+        return {"dx": vjp(j["dy"])[0]}
+
+    return {"fwd": lambda j: {"y": pool_fwd(j["x"])}, "dx": pool_dx}
+
+
+def _relu_whole(wk, spec):
+    """A ReLU runs once over the walk's whole batch."""
+    return ReluSpec((wk.B,) + tuple(spec.shape)) if wk.batched else spec
+
+
+@_route(
+    ReluSpec,
+    fwd=lambda wk, n, x: wk.plan(_relu_whole(wk, n.spec), "fwd")({"x": x})["y"],
+    dx=lambda wk, n, dy: wk.plan(_relu_whole(wk, n.spec), "dx")({"x": wk.act(n), "dy": dy})["dx"],
+)
+def _relu_plans(spec: ReluSpec, interpret: bool):
+    import jax.numpy as jnp
+
+    return {
+        "fwd": lambda j: {"y": jnp.maximum(j["x"], 0.0)},
+        # the sign/select mask pattern of the lowering rule, in jnp
+        "dx": lambda j: {"dx": jnp.where(j["x"] > 0.0, j["dy"], 0.0)},
+    }
+
+
+@_route(
+    BiasSpec,
+    fwd=lambda wk, n, x: wk.plan(n.spec, "fwd")(
+        {"x": x.reshape(-1, n.spec.c), "b": wk.param(n)}
+    )["y"].reshape(x.shape),
+    dw=lambda wk, n, dy: wk.plan(n.spec, "dw")({"dy": dy.reshape(-1, n.spec.c)})["db"],
+    # the walk passes the gradient through: no dispatch
+    dx=lambda wk, n, dy: dy.reshape(wk.act(n).shape),
+)
+def _bias_plans(spec: BiasSpec, interpret: bool):
+    return {
+        "fwd": lambda j: {"y": j["x"] + j["b"][None, :]},
+        "dw": lambda j: {"db": j["dy"].sum(axis=0)},
+        "dx": lambda j: {"dx": j["dy"]},
+    }
+
+
+@_route(
+    FlattenSpec,
+    fwd=lambda wk, n, x: x.reshape((wk.B, n.spec.size) if wk.batched else (n.spec.size,)),
+    dx=lambda wk, n, dy: dy.reshape(
+        (wk.B, *n.spec.in_shape) if wk.batched else tuple(n.spec.in_shape)
+    ),
+)
+def _flatten_plans(spec: FlattenSpec, interpret: bool):
+    return {}  # a zero-copy view: the walk reshapes, no plan runs
+
+
+@_route(SoftmaxXentSpec)
+def _xent_plans(spec: SoftmaxXentSpec, interpret: bool):
+    from repro.kernels.fused import softmax_xent
+
+    def xent_dx(j):
+        dz, rows = softmax_xent(j["z"], j["onehot"], spec.batch)
+        return {"dz": dz, "rows": rows.reshape(-1)}
+
+    return {"dx": xent_dx}
+
+
+@_route(SgdUpdateSpec)
+def _sgd_plans(spec: SgdUpdateSpec, interpret: bool):
+    lr, mu = spec.lr, spec.momentum
+    if not mu:
+        return {"upd": lambda j: {"w_new": j["w"] - lr * j["dw"]}}
+
+    def upd_mom(j):
+        v_new = mu * j["v"] + j["dw"]
+        return {"v_new": v_new, "w_new": j["w"] - lr * v_new}
+
+    return {"upd": upd_mom}
+
+
+def _attention_fwd(wk, n, x):
+    # per-sequence node over token-row activations (rows = B*S)
+    s = n.spec
+    xb = x.reshape(-1, s.seq, 3 * s.d)
+    y = wk.plan(BatchedSpec(s, xb.shape[0]), "fwd")({"x": xb})["y"]
+    return y.reshape(-1, s.d)
+
+
+def _attention_dx(wk, n, dy):
+    s, a_in = n.spec, wk.act(n)
+    xb = a_in.reshape(-1, s.seq, 3 * s.d)
+    return wk.plan(BatchedSpec(s, xb.shape[0]), "dx")(
+        {"x": xb, "dy": dy.reshape(-1, s.seq, s.d)}
+    )["dx"].reshape(a_in.shape)
+
+
+@_route(AttentionSpec, fwd=_attention_fwd, dx=_attention_dx)
+def _attention_plans(spec: AttentionSpec, interpret: bool):
+    import jax
+    import jax.numpy as jnp
+
+    S, H, Dh = spec.seq, spec.n_heads, spec.head_dim
+    D = H * Dh
+
+    def attn_one(x):  # (S, 3D) qkv -> (S, D) context, causal
+        q = x[:, :D].reshape(S, H, Dh).transpose(1, 0, 2)
+        k = x[:, D : 2 * D].reshape(S, H, Dh).transpose(1, 0, 2)
+        v = x[:, 2 * D :].reshape(S, H, Dh).transpose(1, 0, 2)
+        # full fp32 contractions: the TPU default rounds f32 to bf16
+        hi = jax.lax.Precision.HIGHEST
+        sc = jnp.einsum("hid,hjd->hij", q, k, precision=hi) * spec.scale
+        mask = jnp.where(jnp.tril(jnp.ones((S, S), x.dtype)) > 0, 0.0, -1e9)
+        p = jax.nn.softmax(sc + mask[None], axis=-1)
+        ctx = jnp.einsum("hij,hjd->hid", p, v, precision=hi)
+        return ctx.transpose(1, 0, 2).reshape(S, D)
+
+    def attn_dx(j):
+        _, vjp = jax.vjp(attn_one, j["x"])
+        return {"dx": vjp(j["dy"])[0]}
+
+    return {"fwd": lambda j: {"y": attn_one(j["x"])}, "dx": attn_dx}
+
+
+# the wiring layernorm and embedding share: input ``x``, parameter ``w``
+def _fwd_x_w(wk, n, x):
+    return wk.plan(n.spec, "fwd")({"x": x, "w": wk.param(n)})["y"]
+
+
+def _dw_x_dy(wk, n, dy):
+    return wk.plan(n.spec, "dw")({"x": wk.act(n), "dy": dy})["dw"]
+
+
+@_route(
+    LayerNormSpec,
+    fwd=_fwd_x_w,
+    dw=_dw_x_dy,
+    dx=lambda wk, n, dy: wk.plan(n.spec, "dx")({"x": wk.act(n), "w": wk.param(n), "dy": dy})["dx"],
+)
+def _layernorm_plans(spec: LayerNormSpec, interpret: bool):
+    import jax
+    import jax.numpy as jnp
+
+    eps = spec.eps
+
+    def ln_xhat(j):
+        mu = jnp.mean(j["x"], axis=-1, keepdims=True)
+        var = jnp.mean((j["x"] - mu) ** 2, axis=-1, keepdims=True)
+        return (j["x"] - mu) * jax.lax.rsqrt(var + eps)
+
+    def ln_dx(j):
+        xhat = ln_xhat(j)
+        mu = jnp.mean(j["x"], axis=-1, keepdims=True)
+        var = jnp.mean((j["x"] - mu) ** 2, axis=-1, keepdims=True)
+        dyg = j["dy"] * j["w"][0]
+        m1 = jnp.mean(dyg, axis=-1, keepdims=True)
+        m2 = jnp.mean(dyg * xhat, axis=-1, keepdims=True)
+        return {"dx": (dyg - m1 - xhat * m2) * jax.lax.rsqrt(var + eps)}
+
+    return {
+        "fwd": lambda j: {"y": ln_xhat(j) * j["w"][0] + j["w"][1]},
+        "dw": lambda j: {
+            "dw": jnp.stack([(j["dy"] * ln_xhat(j)).sum(axis=0), j["dy"].sum(axis=0)])
+        },
+        "dx": ln_dx,
+    }
+
+
+def _residual_dx(wk, n, dy):
+    gx = wk.plan(n.spec, "dx")({"dy": dy})["dx"]
+    wk.add_grad(n.aux_edges[0], gx)  # the second summand's gradient
+    return gx
+
+
+@_route(
+    ResidualAddSpec,
+    fwd=lambda wk, n, x: wk.plan(n.spec, "fwd")({"x": x, "x2": wk.env[n.aux_edges[0]]})["y"],
+    dx=_residual_dx,
+)
+def _residual_plans(spec: ResidualAddSpec, interpret: bool):
+    return {
+        "fwd": lambda j: {"y": j["x"] + j["x2"]},
+        "dx": lambda j: {"dx": j["dy"]},
+    }
+
+
+@_route(EmbeddingSpec, fwd=_fwd_x_w, dw=_dw_x_dy)
+def _embedding_plans(spec: EmbeddingSpec, interpret: bool):
+    from repro.kernels import streaming
+
+    mm = functools.partial(streaming.streaming_matmul, interpret=interpret)
+    return {
+        "fwd": lambda j: {"y": mm(j["x"], j["w"])},
+        "dw": lambda j: {"dw": mm(j["x"].T, j["dy"])},
+    }
+
+
+def _posembed_fwd(wk, n, x):
+    # -1, not s.batch: mesh shards walk with a local batch
+    s = n.spec
+    xb = x.reshape(-1, s.seq, s.d)
+    return wk.plan(s, "fwd")({"x": xb, "w": wk.param(n)})["y"].reshape(-1, s.d)
+
+
+@_route(
+    PosEmbedSpec,
+    fwd=_posembed_fwd,
+    dw=lambda wk, n, dy: wk.plan(n.spec, "dw")({"dy": dy.reshape(-1, n.spec.seq, n.spec.d)})["dw"],
+    dx=lambda wk, n, dy: wk.plan(n.spec, "dx")(
+        {"dy": dy.reshape(-1, n.spec.seq, n.spec.d)}
+    )["dx"].reshape(-1, n.spec.d),
+)
+def _posembed_plans(spec: PosEmbedSpec, interpret: bool):
+    return {
+        "fwd": lambda j: {"y": j["x"] + j["w"][None]},
+        "dw": lambda j: {"dw": j["dy"].sum(axis=0)},
+        "dx": lambda j: {"dx": j["dy"]},
+    }
 
 
 def _plan_callable(spec, pass_: str, interpret: bool):
@@ -228,10 +534,6 @@ def _plan_callable(spec, pass_: str, interpret: bool):
     what :class:`PlanCache` jits and keeps.
     """
     import jax
-    import jax.numpy as jnp
-
-    from repro.kernels import streaming
-    from repro.lower.fuse import RegionSpec
 
     if isinstance(spec, RegionSpec):
         # one fused kernel for a whole region chain; ``pass_`` is "region"
@@ -239,177 +541,12 @@ def _plan_callable(spec, pass_: str, interpret: bool):
 
         return fused.build_region_callable(spec, interpret=interpret)
 
-    if isinstance(spec, MatmulSpec):
-        if pass_ == "fwd":
-            return lambda j: {
-                "c": streaming.streaming_matmul(j["a"], j["b"], interpret=interpret)
-            }
-        if pass_ == "dw":
-            return lambda j: {
-                "dw": streaming.streaming_matmul(j["a"].T, j["dy"], interpret=interpret)
-            }
-        if pass_ == "dx":
-            return lambda j: {
-                "dx": streaming.streaming_matmul(j["dy"], j["b"].T, interpret=interpret)
-            }
-
-    if isinstance(spec, Conv2dSpec):
-        conv = _conv_plan(spec, pass_, interpret)
-        if conv is not None:
-            # one image: lend it a batch axis of one and take it back off
-            # (dW has no batch axis to remove)
-            def one_image(j):
-                out = conv({k: v if k == "w" else v[None] for k, v in j.items()})
-                return {k: v if k == "dw" else v[0] for k, v in out.items()}
-
-            return one_image
-
-    if isinstance(spec, MaxPool2dSpec):
-        w, s = spec.window, spec.stride
-
-        def pool_fwd(x):
-            return jax.lax.reduce_window(
-                x, -jnp.inf, jax.lax.max, (w, w, 1), (s, s, 1), "VALID"
-            )
-
-        if pass_ == "fwd":
-            return lambda j: {"y": pool_fwd(j["x"])}
-        if pass_ == "dx":
-
-            def pool_dx(j):
-                _, vjp = jax.vjp(pool_fwd, j["x"])
-                return {"dx": vjp(j["dy"])[0]}
-
-            return pool_dx
-
-    if isinstance(spec, ReluSpec):
-        if pass_ == "fwd":
-            return lambda j: {"y": jnp.maximum(j["x"], 0.0)}
-        if pass_ == "dx":
-            # the sign/select mask pattern of the lowering rule, in jnp
-            return lambda j: {"dx": jnp.where(j["x"] > 0.0, j["dy"], 0.0)}
-
-    if isinstance(spec, BiasSpec):
-        if pass_ == "fwd":
-            return lambda j: {"y": j["x"] + j["b"][None, :]}
-        if pass_ == "dw":
-            return lambda j: {"db": j["dy"].sum(axis=0)}
-        if pass_ == "dx":
-            return lambda j: {"dx": j["dy"]}
-
-    if isinstance(spec, SoftmaxXentSpec):
-        if pass_ == "dx":
-            from repro.kernels.fused import softmax_xent
-
-            def xent_dx(j):
-                dz, rows = softmax_xent(j["z"], j["onehot"], spec.batch)
-                return {"dz": dz, "rows": rows.reshape(-1)}
-
-            return xent_dx
-
-    if isinstance(spec, SgdUpdateSpec):
-        if pass_ == "upd":
-            lr, mu = spec.lr, spec.momentum
-            if mu:
-
-                def upd_mom(j):
-                    v_new = mu * j["v"] + j["dw"]
-                    return {"v_new": v_new, "w_new": j["w"] - lr * v_new}
-
-                return upd_mom
-            return lambda j: {"w_new": j["w"] - lr * j["dw"]}
-
-    if isinstance(spec, AttentionSpec):
-        S, H, Dh = spec.seq, spec.n_heads, spec.head_dim
-        D = H * Dh
-
-        def attn_one(x):  # (S, 3D) qkv -> (S, D) context, causal
-            q = x[:, :D].reshape(S, H, Dh).transpose(1, 0, 2)
-            k = x[:, D:2 * D].reshape(S, H, Dh).transpose(1, 0, 2)
-            v = x[:, 2 * D:].reshape(S, H, Dh).transpose(1, 0, 2)
-            # full fp32 contractions: the TPU default rounds f32 to bf16
-            hi = jax.lax.Precision.HIGHEST
-            sc = jnp.einsum("hid,hjd->hij", q, k, precision=hi) * spec.scale
-            mask = jnp.where(
-                jnp.tril(jnp.ones((S, S), x.dtype)) > 0, 0.0, -1e9
-            )
-            p = jax.nn.softmax(sc + mask[None], axis=-1)
-            ctx = jnp.einsum("hij,hjd->hid", p, v, precision=hi)
-            return ctx.transpose(1, 0, 2).reshape(S, D)
-
-        if pass_ == "fwd":
-            return lambda j: {"y": attn_one(j["x"])}
-        if pass_ == "dx":
-
-            def attn_dx(j):
-                _, vjp = jax.vjp(attn_one, j["x"])
-                return {"dx": vjp(j["dy"])[0]}
-
-            return attn_dx
-
-    if isinstance(spec, LayerNormSpec):
-        eps = spec.eps
-
-        def ln_xhat(j):
-            mu = jnp.mean(j["x"], axis=-1, keepdims=True)
-            var = jnp.mean((j["x"] - mu) ** 2, axis=-1, keepdims=True)
-            return (j["x"] - mu) * jax.lax.rsqrt(var + eps)
-
-        if pass_ == "fwd":
-            return lambda j: {"y": ln_xhat(j) * j["w"][0] + j["w"][1]}
-        if pass_ == "dw":
-            return lambda j: {
-                "dw": jnp.stack(
-                    [(j["dy"] * ln_xhat(j)).sum(axis=0), j["dy"].sum(axis=0)]
-                )
-            }
-        if pass_ == "dx":
-
-            def ln_dx(j):
-                xhat = ln_xhat(j)
-                mu = jnp.mean(j["x"], axis=-1, keepdims=True)
-                var = jnp.mean((j["x"] - mu) ** 2, axis=-1, keepdims=True)
-                dyg = j["dy"] * j["w"][0]
-                m1 = jnp.mean(dyg, axis=-1, keepdims=True)
-                m2 = jnp.mean(dyg * xhat, axis=-1, keepdims=True)
-                return {
-                    "dx": (dyg - m1 - xhat * m2) * jax.lax.rsqrt(var + eps)
-                }
-
-            return ln_dx
-
-    if isinstance(spec, ResidualAddSpec):
-        if pass_ == "fwd":
-            return lambda j: {"y": j["x"] + j["x2"]}
-        if pass_ == "dx":
-            return lambda j: {"dx": j["dy"]}
-
-    if isinstance(spec, EmbeddingSpec):
-        if pass_ == "fwd":
-            return lambda j: {
-                "y": streaming.streaming_matmul(j["x"], j["w"],
-                                                interpret=interpret)
-            }
-        if pass_ == "dw":
-            return lambda j: {
-                "dw": streaming.streaming_matmul(j["x"].T, j["dy"],
-                                                 interpret=interpret)
-            }
-
-    if isinstance(spec, PosEmbedSpec):
-        if pass_ == "fwd":
-            return lambda j: {"y": j["x"] + j["w"][None]}
-        if pass_ == "dw":
-            return lambda j: {"dw": j["dy"].sum(axis=0)}
-        if pass_ == "dx":
-            return lambda j: {"dx": j["dy"]}
-
     if isinstance(spec, BatchedSpec):
         if isinstance(spec.spec, Conv2dSpec):
             # the images fold into the streamed matmul rows: a vmap would
             # hand the ANY-space streaming kernel a squeezed batch block,
             # which Mosaic refuses
-            conv = _conv_plan(spec.spec, pass_, interpret)
+            conv = _conv_plans(spec.spec, interpret).get(pass_)
             if conv is not None:
                 return conv
         inner = _plan_callable(spec.spec, pass_, interpret)
@@ -420,9 +557,11 @@ def _plan_callable(spec, pass_: str, interpret: bool):
 
         return batched
 
-    raise TypeError(
-        f"no Pallas route for spec {type(spec).__name__} pass {pass_!r}"
-    )
+    plans = _ROUTES.get(type(spec), {}).get("plans")
+    fn = None if plans is None else plans(spec, interpret).get(pass_)
+    if fn is None:
+        raise TypeError(f"no Pallas route for spec {type(spec).__name__} pass {pass_!r}")
+    return fn
 
 
 class CompiledPlan:
@@ -529,7 +668,7 @@ def _record_cache_delta(reg, cache: PlanCache, before) -> None:
 
 
 def _dispatch_plan(cache: PlanCache, design: str, interpret: bool, col=None):
-    """The graph walkers' (spec, pass) -> plan closure.
+    """The graph walk's (spec, pass) -> plan closure.
 
     With ``col`` (a :class:`repro.obs.trace.TraceCollector`), every plan
     invocation is wrapped in a host-side dispatch span (the wall time jax
@@ -586,9 +725,7 @@ def _fusion_for(program, *, fuse_updates: bool):
     plans = program.meta.setdefault("_fusion_plans", {})
     plan = plans.get(fuse_updates)
     if plan is None:
-        from repro.lower import fuse as fuse_mod
-
-        plan = fuse_mod.plan_fusion(program, fuse_updates=fuse_updates)
+        plan = plan_fusion(program, fuse_updates=fuse_updates)
         plans[fuse_updates] = plan
     return plan
 
@@ -614,35 +751,25 @@ def _step_plan(cache, graph, fusion, design, interpret, *, keep_grads,
                relu_outputs=False):
     """One jitted callable for the WHOLE fused train step.
 
-    The fused walk still dispatches 5-ish plans per step; at millisecond
-    step times that per-plan jit entry overhead dominates the kernels
-    themselves. Caching the entire segment walk as a single plan — keyed by
-    the graph fingerprint plus the fusion plan's segment tuple (RegionSpecs
-    are frozen) — collapses a step to one dispatch, with the region
-    pallas_calls inlined into the step executable at trace time. A
-    TraceCollector sees the step as one dispatch (``train_graph``'s
-    ``dispatch`` span); the executable is the same with or without one.
+    At millisecond step times the jit entry overhead of dispatching plan
+    by plan dominates the kernels. Caching the entire segment walk as one
+    plan — keyed by the graph fingerprint plus the fusion plan's segments
+    (RegionSpecs are frozen) — collapses a step to one dispatch, with every
+    plan inlined into the step executable at trace time. A TraceCollector
+    sees the step as one dispatch (``train_graph``'s ``dispatch`` span);
+    the executable is the same with or without one.
     """
-    segs = tuple(
-        s.step if s.region is None else s.region for s in fusion.segments
-    )
-    key = (
-        "train_step",
-        _graph_fingerprint(graph),
-        segs,
-        keep_grads,
-        relu_outputs,
-        design,
-        bool(interpret),
-    )
+    segs = tuple(s.step if s.region is None else s.region for s in fusion.segments)
+    key = ("train_step", _graph_fingerprint(graph), segs, keep_grads,
+           relu_outputs, design, bool(interpret))
 
     def build():
         plan = _dispatch_plan(cache, design, interpret)
 
         def raw(j):
             return _graph_step_local(
-                graph, j, plan, graph.batch, keep_grads=keep_grads,
-                fusion=fusion, relu_outputs=relu_outputs,
+                graph, j, plan, graph.batch, fusion, keep_grads=keep_grads,
+                relu_outputs=relu_outputs,
             )
 
         return raw
@@ -652,16 +779,13 @@ def _step_plan(cache, graph, fusion, design, interpret, *, keep_grads,
 
 def _record_fusion(reg, fusion) -> None:
     """Book what the fuser covered this step under fusion/."""
-    if reg is None or not reg.enabled or fusion is None:
+    if reg is None or not reg.enabled:
         return
     with reg.scope("fusion"):
         reg.inc("regions", fusion.n_regions)
         reg.inc("fallback_dispatches", len(fusion.fallback_steps))
         reg.inc("fused_commands", fusion.fused_commands)
-        reg.inc(
-            "unfused_commands",
-            fusion.total_commands - fusion.fused_commands,
-        )
+        reg.inc("unfused_commands", fusion.total_commands - fusion.fused_commands)
 
 
 def run_pallas(
@@ -682,20 +806,17 @@ def run_pallas(
     Repeated calls on equal specs reuse one jitted executable from
     ``cache`` (default: the process-wide :data:`PLAN_CACHE`).
 
-    Fused train-step programs execute as ONE cached jitted callable per
-    step (the region kernels inline into it at trace time), so the warm
-    path is a single dispatch — the executor analogue of the paper's
-    "one offload per training step" goal. A train step's outputs also
-    carry ``graph.loss_rows_edge``: each logits row's float32
-    cross-entropy, computed beside the loss gradient on every route (fused,
-    per-node, ``shard_map``), so the host reads one float per row and
-    never the logits.
-
-    ``fuse`` (train-step programs only) routes the graph walk through the
-    :mod:`repro.lower.fuse` region plan — whole fwd/bwd chains as single
-    fused kernels — with per-node dispatch as the fallback for steps
-    without a fusion rule. ``fuse=False`` is the escape hatch: the original
-    one-plan-per-node walk, bit-for-bit the PR-4 behaviour.
+    ``fuse`` (train-step programs only) runs the step as ONE cached jitted
+    callable: the kernels of the :mod:`repro.lower.fuse` region plan and
+    the per-node plans of the steps without a fusion rule inline into it
+    at trace time, so the warm path is a single dispatch (the paper's "one
+    offload per training step"). ``fuse=False`` is the escape hatch: the
+    same graph walk over a plan with no regions, every node pass's cached
+    plan dispatched from the host — the reference the region kernels are
+    tested against. A train step's outputs also carry
+    ``graph.loss_rows_edge``: each logits row's float32 cross-entropy,
+    computed beside the loss gradient on every route, so the host reads
+    one float per row and never the logits.
 
     ``relu_outputs`` (train-step programs only) adds the output edge of
     every ReLU forward the walk runs outside a fused region to the outputs,
@@ -708,16 +829,8 @@ def run_pallas(
     reg = obs.get_active()
     before = _cache_stats(cache) if reg is not None else None
     if program.meta.get("pass") == "train_step":
-        if "mesh" in program.meta:
-            out = _run_pallas_graph_mesh(
-                program, inputs, interpret, cache, fuse=fuse,
-                relu_outputs=relu_outputs,
-            )
-        else:
-            out = _run_pallas_graph(
-                program, inputs, interpret, cache, fuse=fuse,
-                relu_outputs=relu_outputs,
-            )
+        run = _run_pallas_graph_mesh if "mesh" in program.meta else _run_pallas_graph
+        out = run(program, inputs, interpret, cache, fuse=fuse, relu_outputs=relu_outputs)
     else:
         spec = program.meta.get("spec")
         pass_ = program.meta.get("pass", "fwd")
@@ -733,243 +846,82 @@ def run_pallas(
     return out
 
 
+def _walk_plan(program, fuse: bool, *, fuse_updates: bool):
+    """The segments a step walk follows: the fuser's plan, booked under
+    fusion/, or with ``fuse=False`` every step of the schedule as its own
+    per-node segment."""
+    if not fuse:
+        return unfused_plan(program.meta["graph"])
+    fusion = _fusion_for(program, fuse_updates=fuse_updates)
+    _record_fusion(obs.get_active(), fusion)
+    return fusion
+
+
 def _run_pallas_graph(program, inputs, interpret: bool, cache, fuse=True,
                       relu_outputs=False):
-    """Graph-driven Pallas execution of one whole-train-step program.
+    """Walk the graph behind one whole-train-step program on one device.
 
-    Walks the :class:`repro.lower.graph.NetworkGraph` behind ``program`` in
-    the same fwd → loss grad → dW/update/dX schedule the command stream
-    encodes. With ``fuse`` (the default) the walk follows the program's
-    :class:`repro.lower.fuse.FusionPlan`: contiguous fusable chains run as
-    single region kernels, everything else through the cached per-node
-    plans (per-image nodes key as :class:`BatchedSpec`). Outputs carry the
-    program's output-region names — logits, ``d_<param>`` (when kept),
-    ``<param>_new`` and ``v_<param>_new`` — plus the loss rows, like
-    :func:`run_reference`'s, so callers are executor-agnostic.
+    With ``fuse`` the walk (:func:`_graph_step_local`) follows the
+    program's :class:`repro.lower.fuse.FusionPlan` as one cached jitted
+    step; ``fuse=False`` dispatches every node pass's plan from the host.
+    Outputs carry the program's output-region names — logits,
+    ``d_<param>`` (when kept), ``<param>_new`` and ``v_<param>_new`` — plus
+    the loss rows, like :func:`run_reference`'s.
     """
     graph = program.meta["graph"]
     keep_grads = program.meta.get("keep_grads", True)
+    design = program.design.name
     j = _as_jax_f32(inputs)
-    fusion = _fusion_for(program, fuse_updates=True) if fuse else None
-    _record_fusion(obs.get_active(), fusion)
-    if fusion is not None:
-        step = _step_plan(cache, graph, fusion, program.design.name,
-                          interpret, keep_grads=keep_grads,
-                          relu_outputs=relu_outputs)
+    fusion = _walk_plan(program, fuse, fuse_updates=True)
+    if fuse:
+        step = _step_plan(cache, graph, fusion, design, interpret,
+                          keep_grads=keep_grads, relu_outputs=relu_outputs)
         return step(j)
-    plan = _dispatch_plan(cache, program.design.name, interpret,
-                          obs_trace.get_active_trace())
-    return _graph_step_local(graph, j, plan, graph.batch,
+    plan = _dispatch_plan(cache, design, interpret, obs_trace.get_active_trace())
+    return _graph_step_local(graph, j, plan, graph.batch, fusion,
                              keep_grads=keep_grads, relu_outputs=relu_outputs)
 
 
 def _relu_edges(graph, fusion) -> tuple[str, ...]:
-    """Output edges of the ReLU forwards a walk runs outside fused regions
-    (all of them without ``fusion``): the ``relu_outputs`` of a step."""
-    ran = None if fusion is None else set(fusion.fallback_steps)
-    return tuple(
-        n.out_edge for n in graph.nodes
-        if isinstance(n.spec, ReluSpec)
-        and (ran is None or f"{n.name}:fwd" in ran)
-    )
+    """Output edges of the ReLU forwards a walk runs outside fused regions:
+    the ``relu_outputs`` of a step."""
+    ran = set(fusion.fallback_steps)
+    return tuple(n.out_edge for n in graph.nodes
+                 if isinstance(n.spec, ReluSpec) and f"{n.name}:fwd" in ran)
 
 
-def _graph_step_local(graph, j, plan, B, *, keep_grads=True,
-                      grad_reduce=None, batched=None, fusion=None,
-                      relu_outputs=False):
-    """One train step over ``B``-image arrays through cached per-node plans.
+def _graph_step_local(graph, j, plan, B, fusion, *, keep_grads=True,
+                      grad_reduce=None, batched=None, relu_outputs=False):
+    """The executor's one graph walk: a train step over ``B``-image arrays.
 
-    ``B`` is the batch the arrays actually carry — the graph's full batch
-    on the single-device path, the per-shard slice inside the mesh route's
-    ``shard_map`` body (where ``grad_reduce`` is the cross-shard psum that
-    realizes the gradient allreduce; the loss plan's 1/B_global scale makes
-    the psum a batch mean). ``batched`` forces a leading batch axis on the
-    activations even at ``B == 1`` — a mesh shard of one image still
-    carries its axis so the out-spec concatenation works. The walk mirrors
-    the command stream's fwd → loss grad → dW/update/dX schedule exactly;
-    with ``fusion`` set it follows the fusion plan's segments instead —
-    the same schedule, chains collapsed into region dispatches.
-    ``relu_outputs`` adds the :func:`_relu_edges` activations to the outputs.
-    Each step of the walk runs under ``jax.named_scope("<node>:<pass>")``,
-    so its operations carry that name in the compiled step.
+    It follows ``fusion``'s segments in the command stream's fwd → loss
+    grad → dW/update/dX order: a region segment is one fused kernel, a step
+    segment one per-node plan wired by its node type's :data:`_ROUTES` entry.
+    ``B`` is the batch the arrays carry — the graph's full batch on one
+    device, the per-shard slice in the mesh route's ``shard_map`` body,
+    where ``grad_reduce`` is the cross-shard psum (the loss plan's
+    1/B_global scale makes it a batch mean; regions with fused updates
+    exist only off the mesh, where it is the identity). ``batched`` keeps a
+    leading batch axis at ``B == 1``, as a one-image mesh shard needs.
+    ``relu_outputs`` adds the :func:`_relu_edges` activations to the
+    outputs. Each segment runs under ``jax.named_scope("<node>:<pass>")``
+    (a region under its label), so its operations carry that name in the
+    compiled step.
     """
     import jax
 
     reduce = grad_reduce or (lambda g: g)
-    batched = (B > 1) if batched is None else batched
-    if fusion is not None:
-        outs, env = _walk_fused(graph, j, plan, B, fusion,
-                                keep_grads=keep_grads, reduce=reduce,
-                                batched=batched)
-        if relu_outputs:
-            outs.update((e, env[e]) for e in _relu_edges(graph, fusion))
-        return outs
-
-    def bspec(spec):
-        return BatchedSpec(spec, B) if batched else spec
-
-    # forward
-    acts = {graph.input_edge: j[graph.input_edge]}
-    for node in graph.nodes:
-        with jax.named_scope(f"{node.name}:fwd"):
-            s, a = node.spec, acts[node.in_edge]
-            if isinstance(s, Conv2dSpec):
-                y = plan(bspec(s), "fwd")({"x": a, "w": j[node.param]})["y"]
-            elif isinstance(s, MatmulSpec):
-                y = plan(s, "fwd")({"a": a, "b": j[node.param]})["c"]
-            elif isinstance(s, BiasSpec):
-                y = plan(s, "fwd")({"x": a.reshape(-1, s.c), "b": j[node.param]})
-                y = y["y"].reshape(a.shape)
-            elif isinstance(s, ReluSpec):
-                whole = ReluSpec((B,) + tuple(s.shape)) if batched else s
-                y = plan(whole, "fwd")({"x": a})["y"]
-            elif isinstance(s, MaxPool2dSpec):
-                y = plan(bspec(s), "fwd")({"x": a})["y"]
-            elif isinstance(s, FlattenSpec):
-                y = a.reshape((B, s.size) if batched else (s.size,))
-            elif isinstance(s, AttentionSpec):
-                # per-sequence node over token-row activations (rows = B*S)
-                xb = a.reshape(-1, s.seq, 3 * s.d)
-                y = plan(BatchedSpec(s, xb.shape[0]), "fwd")({"x": xb})["y"]
-                y = y.reshape(-1, s.d)
-            elif isinstance(s, LayerNormSpec):
-                y = plan(s, "fwd")({"x": a, "w": j[node.param]})["y"]
-            elif isinstance(s, ResidualAddSpec):
-                y = plan(s, "fwd")(
-                    {"x": a, "x2": acts[node.aux_edges[0]]}
-                )["y"]
-            elif isinstance(s, EmbeddingSpec):
-                y = plan(s, "fwd")({"x": a, "w": j[node.param]})["y"]
-            elif isinstance(s, PosEmbedSpec):
-                # -1, not s.batch: mesh shards walk with a local batch
-                xb = a.reshape(-1, s.seq, s.d)
-                y = plan(s, "fwd")({"x": xb, "w": j[node.param]})["y"]
-                y = y.reshape(-1, s.d)
-            else:
-                raise TypeError(f"no graph route for {type(s).__name__}")
-            acts[node.out_edge] = y
-
-    logits = acts[graph.logits_edge]
-    outs = {graph.logits_edge: logits}
-    if relu_outputs:
-        outs.update((e, acts[e]) for e in _relu_edges(graph, None))
-
-    # loss gradient seeds the per-edge gradient map; DAG fan-out edges
-    # accumulate one contribution per consumer (matching the compiled
-    # program's partial + accumulate-step schedule)
-    with jax.named_scope("loss:dx"):
-        loss = plan(graph.loss, "dx")(
-            {"z": logits, "onehot": j[graph.label_edge]}
-        )
-    outs[graph.loss_rows_edge] = loss["rows"]
-    grads = {graph.logits_edge: loss["dz"]}
-
-    def add_grad(edge, v):
-        grads[edge] = grads[edge] + v if edge in grads else v
-
-    # backward: dW -> update -> dX per node, in reverse
-    for node in reversed(graph.nodes):
-        s, a_in = node.spec, acts[node.in_edge]
-        g = grads[node.out_edge]
-        if node.param is not None:
-            with jax.named_scope(f"{node.name}:dw"):
-                p = node.param
-                if isinstance(s, Conv2dSpec):
-                    dw = plan(bspec(s), "dw")({"x": a_in, "dy": g})["dw"]
-                elif isinstance(s, MatmulSpec):
-                    dw = plan(s, "dw")({"a": a_in, "dy": g})["dw"]
-                elif isinstance(s, BiasSpec):
-                    dw = plan(s, "dw")({"dy": g.reshape(-1, s.c)})["db"]
-                elif isinstance(s, (LayerNormSpec, EmbeddingSpec)):
-                    dw = plan(s, "dw")({"x": a_in, "dy": g})["dw"]
-                elif isinstance(s, PosEmbedSpec):
-                    dw = plan(s, "dw")(
-                        {"dy": g.reshape(-1, s.seq, s.d)}
-                    )["dw"]
-                else:
-                    raise TypeError(f"no dW route for {type(s).__name__}")
-                dw = reduce(dw)
-                if keep_grads:
-                    outs[f"d_{p}"] = dw
-            with jax.named_scope(f"{node.name}:upd"):
-                u_spec = SgdUpdateSpec(
-                    n=dw.size, lr=graph.lr, momentum=graph.momentum
-                )
-                u_in = {"w": j[p].reshape(-1), "dw": dw.reshape(-1)}
-                if graph.momentum:
-                    u_in["v"] = j[f"v_{p}"].reshape(-1)
-                u = plan(u_spec, "upd")(u_in)
-                outs[f"{p}_new"] = u["w_new"].reshape(j[p].shape)
-                if graph.momentum:
-                    outs[f"v_{p}_new"] = u["v_new"].reshape(j[p].shape)
-        if node.in_edge == graph.input_edge:
-            continue
-        with jax.named_scope(f"{node.name}:dx"):
-            if isinstance(s, Conv2dSpec):
-                gx = plan(bspec(s), "dx")({"dy": g, "w": j[node.param]})["dx"]
-            elif isinstance(s, MatmulSpec):
-                gx = plan(s, "dx")({"dy": g, "b": j[node.param]})["dx"]
-            elif isinstance(s, ReluSpec):
-                whole = ReluSpec((B,) + tuple(s.shape)) if batched else s
-                gx = plan(whole, "dx")({"x": a_in, "dy": g})["dx"]
-            elif isinstance(s, MaxPool2dSpec):
-                gx = plan(bspec(s), "dx")({"x": a_in, "dy": g})["dx"]
-            elif isinstance(s, AttentionSpec):
-                xb = a_in.reshape(-1, s.seq, 3 * s.d)
-                gx = plan(BatchedSpec(s, xb.shape[0]), "dx")(
-                    {"x": xb, "dy": g.reshape(-1, s.seq, s.d)}
-                )["dx"].reshape(a_in.shape)
-            elif isinstance(s, LayerNormSpec):
-                gx = plan(s, "dx")(
-                    {"x": a_in, "w": j[node.param], "dy": g}
-                )["dx"]
-            elif isinstance(s, ResidualAddSpec):
-                gx = plan(s, "dx")({"dy": g})["dx"]
-                add_grad(node.aux_edges[0], gx)
-            elif isinstance(s, PosEmbedSpec):
-                gx = plan(s, "dx")(
-                    {"dy": g.reshape(-1, s.seq, s.d)}
-                )["dx"].reshape(-1, s.d)
-            elif isinstance(s, (FlattenSpec, BiasSpec)):
-                gx = g.reshape(a_in.shape)
-            else:
-                raise TypeError(f"no dX route for {type(s).__name__}")
-            add_grad(node.in_edge, gx)
-    return outs
-
-
-def _walk_fused(graph, j, plan, B, fusion, *, keep_grads, reduce, batched):
-    """The fused segment walk: region kernels + per-node fallback steps.
-    Returns the step's outputs and the walk's edge environment.
-
-    Activations and activation gradients live in ``env`` keyed by edge
-    name (gradient of edge ``e`` is ``d_<e>``) so region dispatches and
-    fallback steps compose in any interleaving the fusion plan produced.
-    Regions containing fused SGD updates require ``reduce`` to be the
-    identity — the fuser only emits them on the single-device path.
-    """
-    import dataclasses
-
-    import jax
-
+    wk = _Walk(j, plan, B, (B > 1) if batched is None else batched)
+    env = wk.env
+    env[graph.input_edge] = j[graph.input_edge]
     nodes = {n.name: n for n in graph.nodes}
-    env = {graph.input_edge: j[graph.input_edge]}
     outs: dict = {}
 
-    def bspec(spec):
-        return BatchedSpec(spec, B) if batched else spec
-
-    def add_grad(edge, v):
-        key = f"d_{edge}"
-        env[key] = env[key] + v if key in env else v
-
-    def exec_step(key):
+    def run_step(key):
         name, pass_ = key.split(":")
         if pass_ == "acc":
-            # fan-out accumulate: the jax walk sums contributions into
-            # d_<edge> as each consumer's dx lands, so by the time the
-            # compiled schedule reaches the acc step there is nothing
-            # left to do
+            # fan-out accumulate: each consumer's dx already added its
+            # contribution into d_<edge> as it landed
             return
         if name == "loss":
             loss = plan(graph.loss, "dx")(
@@ -979,72 +931,10 @@ def _walk_fused(graph, j, plan, B, fusion, *, keep_grads, reduce, batched):
             env[graph.loss_rows_edge] = loss["rows"]
             return
         node = nodes[name]
-        s = node.spec
-        if pass_ == "fwd":
-            a = env[node.in_edge]
-            if isinstance(s, Conv2dSpec):
-                y = plan(bspec(s), "fwd")({"x": a, "w": j[node.param]})["y"]
-            elif isinstance(s, MatmulSpec):
-                y = plan(s, "fwd")({"a": a, "b": j[node.param]})["c"]
-            elif isinstance(s, BiasSpec):
-                y = plan(s, "fwd")(
-                    {"x": a.reshape(-1, s.c), "b": j[node.param]}
-                )["y"].reshape(a.shape)
-            elif isinstance(s, ReluSpec):
-                whole = ReluSpec((B,) + tuple(s.shape)) if batched else s
-                y = plan(whole, "fwd")({"x": a})["y"]
-            elif isinstance(s, MaxPool2dSpec):
-                y = plan(bspec(s), "fwd")({"x": a})["y"]
-            elif isinstance(s, FlattenSpec):
-                y = a.reshape((B, s.size) if batched else (s.size,))
-            elif isinstance(s, AttentionSpec):
-                xb = a.reshape(-1, s.seq, 3 * s.d)
-                y = plan(BatchedSpec(s, xb.shape[0]), "fwd")({"x": xb})["y"]
-                y = y.reshape(-1, s.d)
-            elif isinstance(s, LayerNormSpec):
-                y = plan(s, "fwd")({"x": a, "w": j[node.param]})["y"]
-            elif isinstance(s, ResidualAddSpec):
-                y = plan(s, "fwd")(
-                    {"x": a, "x2": env[node.aux_edges[0]]}
-                )["y"]
-            elif isinstance(s, EmbeddingSpec):
-                y = plan(s, "fwd")({"x": a, "w": j[node.param]})["y"]
-            elif isinstance(s, PosEmbedSpec):
-                # -1, not s.batch: mesh shards walk with a local batch
-                xb = a.reshape(-1, s.seq, s.d)
-                y = plan(s, "fwd")({"x": xb, "w": j[node.param]})["y"]
-                y = y.reshape(-1, s.d)
-            else:
-                raise TypeError(f"no graph route for {type(s).__name__}")
-            env[node.out_edge] = y
-        elif pass_ == "dw":
-            g = env[f"d_{node.out_edge}"]
-            if isinstance(s, Conv2dSpec):
-                dw = plan(bspec(s), "dw")(
-                    {"x": env[node.in_edge], "dy": g}
-                )["dw"]
-            elif isinstance(s, MatmulSpec):
-                dw = plan(s, "dw")({"a": env[node.in_edge], "dy": g})["dw"]
-            elif isinstance(s, BiasSpec):
-                dw = plan(s, "dw")({"dy": g.reshape(-1, s.c)})["db"]
-            elif isinstance(s, (LayerNormSpec, EmbeddingSpec)):
-                dw = plan(s, "dw")({"x": env[node.in_edge], "dy": g})["dw"]
-            elif isinstance(s, PosEmbedSpec):
-                dw = plan(s, "dw")(
-                    {"dy": g.reshape(-1, s.seq, s.d)}
-                )["dw"]
-            else:
-                raise TypeError(f"no dW route for {type(s).__name__}")
-            dw = reduce(dw)
-            env[f"d_{node.param}"] = dw
-            if keep_grads:
-                outs[f"d_{node.param}"] = dw
-        elif pass_ == "upd":
+        if pass_ == "upd":
             p = node.param
             dw = env[f"d_{p}"]
-            u_spec = SgdUpdateSpec(
-                n=dw.size, lr=graph.lr, momentum=graph.momentum
-            )
+            u_spec = SgdUpdateSpec(n=dw.size, lr=graph.lr, momentum=graph.momentum)
             u_in = {"w": j[p].reshape(-1), "dw": dw.reshape(-1)}
             if graph.momentum:
                 u_in["v"] = j[f"v_{p}"].reshape(-1)
@@ -1052,63 +942,38 @@ def _walk_fused(graph, j, plan, B, fusion, *, keep_grads, reduce, batched):
             outs[f"{p}_new"] = u["w_new"].reshape(j[p].shape)
             if graph.momentum:
                 outs[f"v_{p}_new"] = u["v_new"].reshape(j[p].shape)
+            return
+        wire = _ROUTES.get(type(node.spec), {}).get(pass_)
+        if wire is None:
+            raise TypeError(f"no Pallas route for spec {type(node.spec).__name__} pass {pass_!r}")
+        if pass_ == "fwd":
+            env[node.out_edge] = wire(wk, node, env[node.in_edge])
+        elif pass_ == "dw":
+            dw = reduce(wire(wk, node, env[f"d_{node.out_edge}"]))
+            env[f"d_{node.param}"] = dw
+            if keep_grads:
+                outs[f"d_{node.param}"] = dw
         else:  # dx
-            g = env[f"d_{node.out_edge}"]
-            if isinstance(s, Conv2dSpec):
-                gx = plan(bspec(s), "dx")({"dy": g, "w": j[node.param]})["dx"]
-            elif isinstance(s, MatmulSpec):
-                gx = plan(s, "dx")({"dy": g, "b": j[node.param]})["dx"]
-            elif isinstance(s, ReluSpec):
-                whole = ReluSpec((B,) + tuple(s.shape)) if batched else s
-                gx = plan(whole, "dx")(
-                    {"x": env[node.in_edge], "dy": g}
-                )["dx"]
-            elif isinstance(s, MaxPool2dSpec):
-                gx = plan(bspec(s), "dx")(
-                    {"x": env[node.in_edge], "dy": g}
-                )["dx"]
-            elif isinstance(s, AttentionSpec):
-                a_in = env[node.in_edge]
-                xb = a_in.reshape(-1, s.seq, 3 * s.d)
-                gx = plan(BatchedSpec(s, xb.shape[0]), "dx")(
-                    {"x": xb, "dy": g.reshape(-1, s.seq, s.d)}
-                )["dx"].reshape(a_in.shape)
-            elif isinstance(s, LayerNormSpec):
-                gx = plan(s, "dx")(
-                    {"x": env[node.in_edge], "w": j[node.param], "dy": g}
-                )["dx"]
-            elif isinstance(s, ResidualAddSpec):
-                gx = plan(s, "dx")({"dy": g})["dx"]
-                add_grad(node.aux_edges[0], gx)
-            elif isinstance(s, PosEmbedSpec):
-                gx = plan(s, "dx")(
-                    {"dy": g.reshape(-1, s.seq, s.d)}
-                )["dx"].reshape(-1, s.d)
-            elif isinstance(s, FlattenSpec):
-                shape = tuple(s.in_shape)
-                gx = g.reshape((B,) + shape if batched else shape)
-            else:  # BiasSpec dx: shape-preserving passthrough
-                gx = g.reshape(env[node.in_edge].shape)
-            add_grad(node.in_edge, gx)
+            wk.add_grad(node.in_edge, wire(wk, node, env[f"d_{node.out_edge}"]))
 
     for seg in fusion.segments:
         if seg.region is None:
             with jax.named_scope(seg.step):
-                exec_step(seg.step)
+                run_step(seg.step)
             continue
         region = seg.region
         if region.batch != B:
-            region = dataclasses.replace(region, batch=B)
+            region = replace(region, batch=B)
         ins = {}
         for name, is_b in region.inputs:
             v = env[name] if name in env else j[name]
-            ins[name] = v[None] if (is_b and not batched) else v
+            ins[name] = v[None] if (is_b and not wk.batched) else v
         with jax.named_scope(region.label):
             ro = plan(region, "region")(ins)
         for name, kind in region.outputs:
             v = ro[name]
             if kind == "batched":
-                env[name] = v if batched else v[0]
+                env[name] = v if wk.batched else v[0]
             elif name.startswith("d_"):
                 dw = reduce(v)
                 env[name] = dw
@@ -1119,7 +984,9 @@ def _walk_fused(graph, j, plan, B, fusion, *, keep_grads, reduce, batched):
     outs[graph.logits_edge] = env[graph.logits_edge]
     # a region's loss stage leaves its rows as a column
     outs[graph.loss_rows_edge] = env[graph.loss_rows_edge].reshape(-1)
-    return outs, env
+    if relu_outputs:
+        outs.update((e, env[e]) for e in _relu_edges(graph, fusion))
+    return outs
 
 
 def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
@@ -1127,41 +994,29 @@ def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
     """Data-parallel execution of a mesh-sharded train-step program.
 
     The batch shards over a ``(pod, data)`` jax device mesh shaped like the
-    HMC mesh (the same DP-axis convention as :mod:`repro.parallel.sharding`)
-    via ``shard_map``; each shard walks the graph on its slice through the
-    shared :class:`PlanCache`, and the gradient allreduce epilogue is a
-    cross-shard ``psum`` — a batch *mean* because the loss plan already
-    scales by 1 / B_global. Updated weights come back replicated, exactly
-    like the allgather of the command-level epilogue. With fewer jax
-    devices than HMCs, compiled kernels raise before any kernel runs; only
-    the interpreter (CPU tests) walks the mesh program unsharded on the
-    full batch — the same numerics, minus the parallelism.
+    HMC mesh (the DP-axis convention of :mod:`repro.parallel.sharding`) via
+    ``shard_map``; each shard runs the graph walk on its slice, and the
+    gradient allreduce is a cross-shard ``psum`` — a batch *mean*, since
+    the loss plan scales by 1 / B_global. Updated weights come back
+    replicated, like the allgather of the command-level epilogue.
 
-    Elastically re-sharded programs (``mesh_meta["alive"]`` set by
-    :func:`repro.lower.mesh.reshard_training_step`) re-enter ``shard_map``
-    over a SHRUNKEN ``(1, n_alive)`` jax mesh — the survivors' batch
-    shards, with the psum spanning only the shrunken mesh. When the batch
-    no longer divides the survivor count (uneven re-chunking), the
-    program walks the full batch on one device (so does the interpreter
-    when too few jax devices remain).
+    Elastically re-sharded programs (``mesh_meta["alive"]``, set by
+    :func:`repro.lower.mesh.reshard_training_step`) shard over a shrunken
+    ``(1, n_alive)`` jax mesh of the survivors. Where the batch does not
+    divide over them, or too few jax devices exist (the interpreter only:
+    compiled kernels raise), the program runs on one device through
+    :func:`_run_pallas_graph` — the same numerics, minus the parallelism.
 
-    2D-sharded programs (``mesh_meta["shard"] == "2d"``) run over a real
-    2D jax mesh with ``("pipe", "data")`` axes shaped like the physical
-    (pipeline rows x tensor/data columns) grid. The jax mesh computes the
-    data-parallel *numerics* — batch sharded over both axes, gradient
-    psum across the full mesh — which is exactly the arithmetic the
-    2D command stream replays (identity-copy communication, disjoint
-    output splits), so gradients match ``jax.grad`` bit-for-tolerance
-    like the 1D path; the pipeline fill/drain and tensor-shard structure
-    live in the command stream and the timing model
-    (:func:`repro.runtime.mesh.time_mesh_step_2d`), not in XLA's
-    schedule. ``fuse_updates=False`` already holds on this path: the
-    cross-mesh psum must run between dW and the SGD update whether the
-    columns are data- or tensor-sharded, so the fuser interaction is
-    identical for both layouts.
+    2D-sharded programs (``mesh_meta["shard"] == "2d"``) shard the batch
+    over a ``("pipe", "data")`` jax mesh shaped like the physical grid: the
+    data-parallel arithmetic the 2D command stream replays, so gradients
+    match ``jax.grad`` like the 1D path. Pipeline fill/drain and tensor
+    shards live in the command stream and the timing model
+    (:func:`repro.runtime.mesh.time_mesh_step_2d`), not in XLA's schedule.
+    Either layout needs the psum between dW and the SGD update, so regions
+    keep the updates as per-node steps (``fuse_updates=False``).
     """
     import jax
-    import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
     from repro import compat
@@ -1174,7 +1029,6 @@ def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
     n_alive = len(alive) if alive is not None else n
     B = graph.batch
     keep_grads = program.meta.get("keep_grads", True)
-    j = _as_jax_f32(inputs)
 
     if jax.device_count() < n_alive and not interpret:
         raise RuntimeError(
@@ -1183,28 +1037,17 @@ def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
             f"program on one device (only the interpreter does)"
         )
     if jax.device_count() < n_alive or B % n_alive:
-        fusion = _fusion_for(program, fuse_updates=True) if fuse else None
-        _record_fusion(obs.get_active(), fusion)
-        if fusion is not None:
-            step = _step_plan(cache, graph, fusion, program.design.name,
-                              interpret, keep_grads=keep_grads,
-                              relu_outputs=relu_outputs)
-            return step(j)
-        plan = _dispatch_plan(cache, program.design.name, interpret,
-                              obs_trace.get_active_trace())
-        return _graph_step_local(graph, j, plan, B, keep_grads=keep_grads,
+        return _run_pallas_graph(program, inputs, interpret, cache, fuse=fuse,
                                  relu_outputs=relu_outputs)
+    j = _as_jax_f32(inputs)
     # inside shard_map the gradient psum must run between dW and the SGD
     # update, so regions keep the updates as per-node fallback dispatches
-    fusion = _fusion_for(program, fuse_updates=False) if fuse else None
-    _record_fusion(obs.get_active(), fusion)
+    fusion = _walk_plan(program, fuse, fuse_updates=False)
 
     # 2D programs name the axes after their meaning (pipeline rows x
     # tensor/data columns); 1D keeps the (pod, data) convention of
     # repro.parallel.sharding. Either way both axes carry batch shards.
-    dp_axes = (
-        ("pipe", "data") if mesh_meta.get("shard") == "2d" else ("pod", "data")
-    )
+    dp_axes = ("pipe", "data") if mesh_meta.get("shard") == "2d" else ("pod", "data")
     # a degraded mesh no longer matches the physical (rows, cols) grid:
     # lay the survivors out along one axis of a shrunken jax mesh
     jax_shape = (rows, cols) if n_alive == n else (1, n_alive)
@@ -1232,9 +1075,9 @@ def _run_pallas_graph_mesh(program, inputs, interpret: bool, cache,
 
     def per_shard(shard_j):
         return _graph_step_local(
-            graph, shard_j, plan, B // n_alive, keep_grads=keep_grads,
+            graph, shard_j, plan, B // n_alive, fusion, keep_grads=keep_grads,
             grad_reduce=lambda g: jax.lax.psum(g, dp_axes), batched=True,
-            fusion=fusion, relu_outputs=relu_outputs,
+            relu_outputs=relu_outputs,
         )
 
     return compat.shard_map(

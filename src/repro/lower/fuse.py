@@ -164,6 +164,13 @@ def step_schedule(graph, keep_grads: bool = True) -> list[str]:
     return keys
 
 
+def unfused_plan(graph) -> FusionPlan:
+    """The plan with no regions: every step of the schedule, in order, as
+    its own per-node segment (the executor's ``fuse=False`` walk)."""
+    keys = step_schedule(graph)
+    return FusionPlan(segments=[Segment(step=k) for k in keys], fallback_steps=keys)
+
+
 def _fusable(node, pass_: str, *, fuse_updates: bool) -> bool:
     """Does this (node, pass) have an in-kernel fusion rule?"""
     s = node.spec

@@ -33,7 +33,8 @@ def test_interpreter_matmul(m, n, k):
     mem[100 : 100 + k * n] = b.ravel()
     cmd = matmul_template(m, n, k, 0, 100, 300)
     out = ntx.ntx_execute(cmd, mem)
-    np.testing.assert_allclose(out[300 : 300 + m * n].reshape(m, n), a @ b, rtol=1e-5)
+    ref = a.astype(np.float64) @ b.astype(np.float64)
+    np.testing.assert_allclose(out[300 : 300 + m * n].reshape(m, n), ref, rtol=1e-5)
 
 
 def test_interpreter_wide_beats_fpu():
